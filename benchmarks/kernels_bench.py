@@ -7,7 +7,7 @@ Pallas kernel and the jnp reference against a NumPy ``searchsorted``
 oracle — and records the reference path's throughput (the path the jax
 replay backend actually uses off-TPU). ``--quick`` keeps the correctness
 gates and shrinks shapes; there are no timing targets in either mode
-(the scan is memory-bound and container noise swamps it).
+(host-clock noise swamps them).
 
 Run:  PYTHONPATH=src python -m benchmarks.run --only kernels [--quick]
 """
@@ -40,16 +40,20 @@ def bench_kernels() -> Bench:
     rows, n, c = (32, 512, 64) if quick else (256, 4096, 1024)
 
     b = Bench("kernels")
-    key = jax.random.PRNGKey(0)
-    k1, k2 = jax.random.split(key)
-    sp = jnp.sort(jax.random.normal(k1, (rows, n)) * 100.0, axis=1)
-    caps = jax.random.normal(k2, (rows, c)) * 100.0
+    rng = np.random.default_rng(0)
+    sp = np.sort(rng.normal(0.0, 100.0, (rows, n)), axis=1)
+    caps = rng.normal(0.0, 100.0, (rows, c))
     expect = _np_counts(sp, caps)
+    # the kernel compares the host-built order-key words of the floats
+    words = [jnp.asarray(w) for x in (sp, caps)
+             for w in rr.order_key_words(x)]
 
-    interp = np.asarray(rr.cap_bucket_scan(sp, caps,
+    interp = np.asarray(rr.cap_bucket_scan(*words,
                                            interpret=rr.default_interpret()))
-    refv = np.asarray(rr.cap_bucket_scan_reference(sp, caps))
-    disp = np.asarray(rr.cap_bucket_counts(sp, caps))
+    with jax.enable_x64():
+        refv = np.asarray(rr.cap_bucket_scan_reference(jnp.asarray(sp),
+                                                       jnp.asarray(caps)))
+        disp = np.asarray(rr.cap_bucket_counts(*words))
 
     b.add("cap_scan_rows_x_configs", float(rows * c))
     b.add("cap_scan_matches_oracle",
@@ -61,12 +65,13 @@ def bench_kernels() -> Bench:
     b.add("cap_scan_default_interpret", float(rr.default_interpret()))
 
     fn = jax.jit(rr.cap_bucket_counts)
-    fn(sp, caps).block_until_ready()
     best = math.inf
-    for _ in range(1 if quick else 5):
-        t0 = time.perf_counter()
-        fn(sp, caps).block_until_ready()
-        best = min(best, time.perf_counter() - t0)
+    with jax.enable_x64():
+        fn(*words).block_until_ready()
+        for _ in range(1 if quick else 5):
+            t0 = time.perf_counter()
+            fn(*words).block_until_ready()
+            best = min(best, time.perf_counter() - t0)
     b.add("cap_scan_mlookups_per_s", rows * c / best / 1e6, seconds=best,
           devices=1)
     return b

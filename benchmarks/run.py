@@ -73,6 +73,9 @@ def main() -> None:
         # so the quick jax-backend rows behave identically on machines
         # with and without accelerators
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    else:
+        from repro.compile_cache import enable_compile_cache
+        enable_compile_cache(pathlib.Path(__file__).resolve().parent.parent)
 
     import repro.obs as obs
     if args.obs:

@@ -218,7 +218,7 @@ def bench_whatif_sweep() -> Bench:
 
             import repro.whatif.backend  # noqa: F401
             n_jax_devices = len(_jax.devices())
-        except Exception:
+        except ImportError:
             n_jax_devices = 0
         if n_jax_devices:
             def jax_sweep(pols):

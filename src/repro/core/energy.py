@@ -34,7 +34,13 @@ class EnergyBreakdown:
 
     @property
     def total_energy_j(self) -> float:
-        return float(sum(self.energy_j.values()))
+        # a plain left fold in DeviceState order. Python's sum() compensates
+        # its rounding only while the items are exact floats, so its result
+        # would depend on whether an entry is a float or an np.float64
+        total = 0.0
+        for e in self.energy_j.values():
+            total += e
+        return float(total)
 
     # ------------------------------------------------------------------ #
     # Whole-window fractions (Fig 3b uses these, denominator = everything)

@@ -77,11 +77,3 @@ def rmsnorm(x, weight, eps: float = 1e-6, block_rows: int = 256,
     interpret = _default_interpret() if interpret is None else interpret
     return _rms.rmsnorm(x, weight, eps=eps, block_rows=block_rows,
                         interpret=interpret)
-
-
-@functools.partial(jax.jit, static_argnames=("use_pallas",))
-def cap_bucket_scan(sorted_p, caps, use_pallas: bool | None = None):
-    """``#{sorted_p[r] > caps[r, c]}`` per row — the run-replay cap scan.
-    ``use_pallas=None`` resolves to the compiled kernel on TPU and the jnp
-    reference elsewhere (:func:`repro.kernels.run_replay.cap_bucket_counts`)."""
-    return _rr.cap_bucket_counts(sorted_p, caps, use_pallas=use_pallas)

@@ -7,15 +7,14 @@ normal runs (tests, benches) see the container's single CPU device.
 """
 from __future__ import annotations
 
-import jax
-
+from repro.distributed.compat import make_mesh
 from repro.distributed.context import DistContext
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_dist(*, multi_pod: bool = False) -> DistContext:
@@ -28,5 +27,5 @@ def make_local_dist(data: int = 1, model: int = 1) -> DistContext:
     """Small mesh over however many (host) devices exist — used by tests."""
     if data * model == 1:
         return DistContext()
-    mesh = jax.make_mesh((data, model), ("data", "model"))
+    mesh = make_mesh((data, model), ("data", "model"))
     return DistContext(mesh=mesh, batch_axes=("data",), model_axis="model")

@@ -149,6 +149,16 @@ class MetricsRegistry:
     def family(self, name: str) -> _Family | None:
         return self._families.get(name)
 
+    def total(self, name: str, **labels) -> float:
+        """Sum of the counter or gauge ``name`` over every label variant
+        that carries ``labels`` (0.0 when nothing was recorded)."""
+        fam = self._families.get(name)
+        if fam is None:
+            return 0.0
+        want = {(k, str(v)) for k, v in labels.items()}
+        return sum(m.value for key, m in fam.metrics.items()
+                   if want <= set(key))
+
     def collect(self) -> Iterator[_Family]:
         """Families in name order (snapshot of the family list)."""
         for name in sorted(self._families):

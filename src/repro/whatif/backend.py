@@ -20,7 +20,10 @@ deadline-aware sweeps of arXiv 2004.08177-style studies — are routine:
   cross-device communication at all;
 * the PowerCap sorted-power cap-bucket scan runs through
   :func:`repro.kernels.run_replay.cap_bucket_counts` — the Pallas kernel
-  on TPU, the vmapped ``searchsorted`` reference elsewhere.
+  on TPU, the vmapped ``searchsorted`` reference elsewhere. It compares
+  order-preserving int32 key words built on the host
+  (:func:`~repro.kernels.run_replay.order_key_words`), because the TPU
+  compiler takes no float64 operand into a Pallas call.
 
 Oracle contract (the NumPy path stays the bit-exactness oracle, enforced
 by tests/test_whatif_backend.py over random grids x chunkings x device
@@ -37,7 +40,10 @@ Host/device split: decisions, gathers and reductions over
 ``(n_streams, n_configs)`` run on the device; per-stream prefix-sum
 construction stays on the host and *shares the StreamIR memos with the
 NumPy path* (same arrays bit-for-bit), and the final fleet fold mirrors
-:func:`repro.core.energy.merge`'s left fold in sorted-stream order.
+:func:`repro.core.energy.merge`'s left fold in sorted-stream order. The
+power-cap family sends the device only its integer count; its float64
+pricing runs on the host, since a TPU emulates float64 and its emulated
+divide can return a float32 quotient.
 """
 from __future__ import annotations
 
@@ -55,8 +61,10 @@ import repro.obs as obs
 from repro.core.energy import EnergyBreakdown
 from repro.core.power_model import ClockLevel, PlatformSpec
 from repro.core.states import ClassifierConfig, DEFAULT_CLASSIFIER, DeviceState
+from repro.distributed.compat import shard_map
 from repro.distributed.context import DistContext
-from repro.kernels.run_replay import cap_bucket_counts
+from repro.kernels.run_replay import (cap_bucket_counts, key_words_to_f64,
+                                      order_key_words)
 from repro.whatif.policies import (CompositeBatch, DownscaleBatch, NoOpBatch,
                                    ParkingBatch, PowerCapBatch,
                                    _NEVER_TRIGGERS, make_batches)
@@ -115,6 +123,11 @@ def _mark_trace(name: str) -> None:
         "jit kernel traces, bumped at trace time only", kernel=name).inc()
 
 
+#: errors the device runtime raises; the sweep's degradation ladder steps
+#: down on one of them only, a device out of memory (RESOURCE_EXHAUSTED)
+DeviceError = jax.errors.JaxRuntimeError
+
+
 def _pow2(n: int, floor: int) -> int:
     return max(int(floor), 1 << max(int(n) - 1, 0).bit_length())
 
@@ -156,22 +169,26 @@ class PackedBucket:
       :meth:`StreamIR.downscale_cums` memo with the NumPy path;
     * ``pk_*``: the run table under the parking counterfactual (state
       padded ``-1`` so padded runs never match a real state);
-    * ``cap_sorted`` / ``cap_top``: sorted-power cap buckets (3 states +
-      the cube-law penalty bucket), ``-inf`` **front**-padded so
-      ``#{p > cap}`` stays exact, prefix ``top`` tables end-padded.
+    * ``cap_hi`` / ``cap_lo`` / ``cap_top``: sorted-power cap buckets (3
+      states + the cube-law penalty bucket) as int32 order-key words,
+      **front**-padded with the key of ``-inf`` so ``#{p > cap}`` stays
+      exact, prefix ``top`` tables end-padded (``cap_top`` is read on the
+      host only).
     """
 
     key: tuple[int, int, int, int]
     idx: np.ndarray                  # [S_b] positions in the packed stream list
     arrays: dict[str, np.ndarray]
-    _jnp: dict[str, jax.Array] | None = None
+    _jnp: dict[str, jax.Array] = dataclasses.field(default_factory=dict)
 
-    def device_arrays(self) -> dict[str, jax.Array]:
-        """Lazily transferred device copies (cached: repeat sweeps and
-        search rounds must not re-upload the packed tensors)."""
-        if self._jnp is None:
-            self._jnp = {k: jnp.asarray(v) for k, v in self.arrays.items()}
-        return self._jnp
+    def device(self, *names: str) -> list[jax.Array]:
+        """Device copies of the named arrays, transferred on first use and
+        cached (repeat sweeps and search rounds must not re-upload the
+        packed tensors; what only the host reads is never sent)."""
+        for name in names:
+            if name not in self._jnp:
+                self._jnp[name] = jnp.asarray(self.arrays[name])
+        return [self._jnp[name] for name in names]
 
 
 @dataclasses.dataclass
@@ -222,8 +239,10 @@ class PackedIR:
             caps = {}
             for j, name in enumerate((_DEEP, _EXEC, _ACTIVE, "penalty")):
                 p_real = int(self.cap_n[s, j])
-                p_pad = a["cap_sorted"].shape[2]
-                caps[name] = (a["cap_sorted"][r, j, p_pad - p_real:],
+                p_pad = a["cap_hi"].shape[2]
+                caps[name] = (key_words_to_f64(
+                                  a["cap_hi"][r, j, p_pad - p_real:],
+                                  a["cap_lo"][r, j, p_pad - p_real:]),
                               a["cap_top"][r, j, :p_real + 1])
             out.append({
                 "lr_s0": a["lr_s0"][r, :k],
@@ -321,6 +340,7 @@ def pack_ir(ir, min_samples: int, min_job_duration_s: float = 2 * 3600.0,
         })
 
     n = len(kept)
+    neg_inf_hi, neg_inf_lo = order_key_words(-np.inf)
     # bucket on the *scan* axis only (the low-run count): the downscale
     # kernel pays one sequential lax.scan step per padded low run, so
     # that axis sets both trace count and step count. The passive axes
@@ -352,7 +372,8 @@ def pack_ir(ir, min_samples: int, min_job_duration_s: float = 2 * 3600.0,
             "pk_state": np.full((sb, rp), -1, np.int32),
             "pk_energy": np.zeros((sb, rp), np.float64),
             "pk_len": np.zeros((sb, rp), np.int64),
-            "cap_sorted": np.full((sb, 4, pp), -np.inf, np.float64),
+            "cap_hi": np.full((sb, 4, pp), neg_inf_hi, np.int32),
+            "cap_lo": np.full((sb, 4, pp), neg_inf_lo, np.int32),
             "cap_top": np.zeros((sb, 4, pp + 1), np.float64),
             "ts_first": np.zeros(sb, np.float64),
         }
@@ -374,7 +395,8 @@ def pack_ir(ir, min_samples: int, min_job_duration_s: float = 2 * 3600.0,
             arrays["pk_len"][r, :nr] = d["pk_len"]
             for j, (sp, top) in enumerate(d["cap_rows"]):
                 p_real = sp.shape[0]
-                arrays["cap_sorted"][r, j, pp - p_real:] = sp
+                (arrays["cap_hi"][r, j, pp - p_real:],
+                 arrays["cap_lo"][r, j, pp - p_real:]) = order_key_words(sp)
                 arrays["cap_top"][r, j, :p_real + 1] = top
                 arrays["cap_top"][r, j, p_real + 1:] = top[-1]
             arrays["ts_first"][r] = d["ts_first"]
@@ -551,25 +573,23 @@ def _integrate_runs_kernel(state, energy, lengths, min_samples):
     return jnp.stack(times, axis=1), jnp.stack(energies, axis=1)
 
 
-def _powercap_kernel(cap_sorted, cap_top, base_e, caps, cbrt_caps, dt):
-    """Every cap fraction against the sorted-power prefix structures:
-    ``k = #{p > cap}`` per (stream, bucket, config) via the run-replay
-    cap scan, then clipped energy / throttle / cube-law penalty are O(1)
-    gathers — the device port of :meth:`PowerCapBatch.apply_runs`."""
+def _powercap_kernel(cap_hi, cap_lo, caps_hi, caps_lo):
+    """``k = #{p > cap}`` per (stream, bucket, config): the run-replay cap
+    scan over the int32 key words of the sorted power buckets
+    ``[S, 4, P]`` and of the caps ``[S, C]``. Returns int32 ``[S, 4, C]``;
+    the float64 pricing off these counts runs on the host
+    (:func:`_run_powercap_family`)."""
     _mark_trace("powercap")
-    s_dim, n_b, p_dim = cap_sorted.shape
-    c_dim = caps.shape[1]
-    rows = cap_sorted.reshape(s_dim * n_b, p_dim)
-    cap_rows = jnp.broadcast_to(
-        caps[:, None, :], (s_dim, n_b, c_dim)).reshape(s_dim * n_b, c_dim)
-    k = cap_bucket_counts(rows, cap_rows).astype(jnp.int64).reshape(
-        s_dim, n_b, c_dim)
-    top_at = jnp.take_along_axis(cap_top, k, axis=2)
-    e_cf = base_e[:, :, None] - (top_at[:, :3, :]
-                                 - k[:, :3, :] * caps[:, None, :]) * dt
-    pen = dt * (top_at[:, 3, :] / cbrt_caps - k[:, 3, :])
-    thr = k[:, 0, :] + k[:, 1, :] + k[:, 2, :]
-    return e_cf, pen, thr
+    s_dim, n_b, p_dim = cap_hi.shape
+    c_dim = caps_hi.shape[1]
+
+    def per_row(words):
+        return jnp.broadcast_to(words[:, None, :], (s_dim, n_b, c_dim)
+                                ).reshape(s_dim * n_b, c_dim)
+
+    return cap_bucket_counts(
+        cap_hi.reshape(s_dim * n_b, p_dim), cap_lo.reshape(s_dim * n_b, p_dim),
+        per_row(caps_hi), per_row(caps_lo)).reshape(s_dim, n_b, c_dim)
 
 
 #: compiled-callable cache: (kernel name, mesh, axis) -> jitted fn.
@@ -580,37 +600,28 @@ _FN_CACHE: dict[tuple, object] = {}
 
 _DS_STREAM_SPECS = (P(None, None),) * 5 + (P(None, None), P(None, None, None),
                                            P(None), P())
-_CAP_STREAM_SPECS = (P(None, None, None), P(None, None, None), P(None, None))
+_KERNELS = {"downscale": _downscale_kernel, "powercap": _powercap_kernel,
+            "integrate": _integrate_runs_kernel}
 
 
 def _get_fn(name: str, dist: DistContext | None):
-    dist_on = dist is not None and dist.enabled
+    dist_on = dist is not None and dist.enabled and name != "integrate"
     key = (name, dist.mesh if dist_on else None,
            dist.batch_axes[0] if dist_on else None)
     fn = _FN_CACHE.get(key)
     if fn is not None:
         return fn
-    if name == "downscale":
-        kernel, stream_specs, n_cfg, n_out = (
-            _downscale_kernel, _DS_STREAM_SPECS, 2, 7)
-    elif name == "powercap":
-        kernel, stream_specs, n_cfg, n_out = (
-            _powercap_kernel, _CAP_STREAM_SPECS + (P(None, None),), 0, 0)
-    else:
-        kernel = _integrate_runs_kernel
-        fn = _FN_CACHE[key] = jax.jit(kernel)
-        return fn
+    kernel = _KERNELS[name]
     if dist_on:
-        from jax.experimental.shard_map import shard_map
         ax = dist.batch_axes[0]
         if name == "downscale":
-            in_specs = stream_specs + (P(ax),) * n_cfg
-            out_specs = (P(None, ax),) * n_out
+            in_specs = _DS_STREAM_SPECS + (P(ax),) * 2
+            out_specs = (P(None, ax),) * 7
         else:
-            in_specs = _CAP_STREAM_SPECS + (P(None, ax), P(None, ax), P())
-            out_specs = (P(None, None, ax), P(None, ax), P(None, ax))
+            in_specs = (P(None, None, None),) * 2 + (P(None, ax),) * 2
+            out_specs = P(None, None, ax)
         kernel = shard_map(kernel, mesh=dist.mesh, in_specs=in_specs,
-                           out_specs=out_specs, check_rep=False)
+                           out_specs=out_specs, check_vma=False)
     fn = _FN_CACHE[key] = jax.jit(kernel)
     return fn
 
@@ -645,7 +656,7 @@ def jax_integrate_runs(states: np.ndarray, energy: np.ndarray,
     if energy.ndim == 1:
         energy = energy[None, :]
     c, r = energy.shape
-    with jax.experimental.enable_x64():
+    with jax.enable_x64():
         fn = _get_fn("integrate", None)
         t, e = fn(
             jnp.asarray(np.broadcast_to(
@@ -720,10 +731,9 @@ def _run_downscale_family(packed: PackedIR, batch, dist, dt):
            [np.zeros((s, p_real)) for _ in range(4)]
     fn = _get_fn("downscale", dist)
     for bucket in packed.buckets:
-        a = bucket.device_arrays()
-        res = fn(a["lr_s0"], a["lr_len"], a["lr_busy"], a["lr_valid"],
-                 a["lr_trail"], a["cum_res"], a["ds_cum"], a["ts_first"],
-                 dt, trig, y)
+        res = fn(*bucket.device("lr_s0", "lr_len", "lr_busy", "lr_valid",
+                                "lr_trail", "cum_res", "ds_cum",
+                                "ts_first"), dt, trig, y)
         for dst, arr in zip(outs, res):
             dst[bucket.idx] = np.asarray(arr)[:, :p_real]
     nd, nr, th, se_hi, sa_hi, se_lo, sa_lo = outs
@@ -744,8 +754,7 @@ def _park_tables(packed: PackedIR) -> tuple[np.ndarray, np.ndarray]:
         fn = _get_fn("integrate", None)
         ms = jnp.asarray(packed.min_samples, jnp.int64)
         for bucket in packed.buckets:
-            a = bucket.device_arrays()
-            t, e = fn(a["pk_state"], a["pk_energy"], a["pk_len"], ms)
+            t, e = fn(*bucket.device("pk_state", "pk_energy", "pk_len"), ms)
             t_out[bucket.idx] = np.asarray(t) * packed.dt_s
             e_out[bucket.idx] = np.asarray(e) * packed.dt_s
         packed.park_time = t_out
@@ -753,33 +762,34 @@ def _park_tables(packed: PackedIR) -> tuple[np.ndarray, np.ndarray]:
     return packed.park_time, packed.park_energy
 
 
-def _run_powercap_family(packed: PackedIR, batch, dist, dt):
+def _run_powercap_family(packed: PackedIR, batch, dist, dt: float):
     """Cap kernel over every bucket: ``(energy_cf [S,3,C], penalty
-    [S,C], throttled [S,C])``. Caps and their cube roots are host-built
-    per stream platform (``frac * tdp_w``, same floats as NumPy)."""
+    [S,C], throttled [S,C])``. Caps are host-built per stream platform
+    (``frac * tdp_w``, same floats as NumPy). The device counts
+    ``k = #{p > cap}``, which is exact; the host gathers the prefix sums
+    at ``k`` and prices them in float64 with
+    :meth:`PowerCapBatch.apply_runs`'s own expressions, because a TPU
+    emulates float64 and its emulated divide can return a float32
+    quotient."""
     c_real = len(batch.policies)
     c_pad = _config_pad(c_real, dist)
-    # pad with a huge finite cap (k = 0 lanes): +inf would make the
-    # clipped-energy term 0 * inf = NaN
-    fracs = _pad_cols(batch._fracs, c_pad, 1e300)
-    caps = np.where(np.arange(c_pad) < c_real,
-                    fracs[None, :] * packed.tdp[:, None], 1e300)
-    cbrt_caps = np.cbrt(caps)
-    s = packed.n_streams
-    e_cf = np.zeros((s, 3, c_real))
-    pen = np.zeros((s, c_real))
-    thr = np.zeros((s, c_real), np.int64)
+    caps = batch._fracs[None, :] * packed.tdp[:, None]
+    # padded lanes get the largest key: no sample is above them
+    caps_hi, caps_lo = order_key_words(_pad_cols(caps, c_pad, np.inf))
+    k = np.zeros((packed.n_streams, 4, c_real), np.int64)
+    top_at = np.zeros((packed.n_streams, 4, c_real))
     fn = _get_fn("powercap", dist)
-    caps_j = jnp.asarray(caps)
-    cbrt_j = jnp.asarray(cbrt_caps)
     for bucket in packed.buckets:
-        a = bucket.device_arrays()
-        base_e = jnp.asarray(packed.base_energy[bucket.idx])
-        e_b, p_b, t_b = fn(a["cap_sorted"], a["cap_top"], base_e,
-                           caps_j[bucket.idx], cbrt_j[bucket.idx], dt)
-        e_cf[bucket.idx] = np.asarray(e_b)[:, :, :c_real]
-        pen[bucket.idx] = np.asarray(p_b)[:, :c_real]
-        thr[bucket.idx] = np.asarray(t_b)[:, :c_real]
+        k_b = np.asarray(fn(*bucket.device("cap_hi", "cap_lo"),
+                            jnp.asarray(caps_hi[bucket.idx]),
+                            jnp.asarray(caps_lo[bucket.idx])))[:, :, :c_real]
+        k[bucket.idx] = k_b
+        top_at[bucket.idx] = np.take_along_axis(bucket.arrays["cap_top"],
+                                                k_b.astype(np.int64), axis=2)
+    e_cf = packed.base_energy[:, :, None] - (
+        top_at[:, :3, :] - k[:, :3, :] * caps[:, None, :]) * dt
+    pen = dt * (top_at[:, 3, :] / np.cbrt(caps) - k[:, 3, :])
+    thr = k[:, 0, :] + k[:, 1, :] + k[:, 2, :]
     return e_cf, pen, thr
 
 
@@ -810,7 +820,8 @@ def replay_ir_outcomes(
     the sweep kernel routes anything else through the row path.
 
     ``dist`` shards the config axis over a mesh from
-    :func:`config_mesh`; results are identical for every mesh shape.
+    :func:`config_mesh`; results hold the NumPy contract for every mesh
+    shape.
     Returns ``(outcomes in grid order, n_rows, n_runs)``.
     """
     if classifier != ir.config.classifier:
@@ -853,7 +864,7 @@ def replay_ir_outcomes(
     thr = np.zeros((s, n_cfg), np.int64)
 
     with obs.span("backend.kernels", configs=n_cfg, streams=s), \
-         jax.experimental.enable_x64():
+         jax.enable_x64():
         dt_j = jnp.asarray(dt, jnp.float64)
         for batch, idxs in make_batches(policies):
             ci = np.asarray(idxs, dtype=np.int64)
@@ -884,7 +895,7 @@ def replay_ir_outcomes(
                     [p.resume_latency_s for p in batch.policies])[None, :]
             elif isinstance(batch, PowerCapBatch):
                 e_cf, p_cap, th = _run_powercap_family(
-                    packed, batch, dist, dt_j)
+                    packed, batch, dist, dt)
                 cf_energy[:, :, ci] = e_cf
                 pen[:, ci] = p_cap
                 thr[:, ci] = th
@@ -935,7 +946,7 @@ def replay_ir_outcomes(
         fleet_be = packed.base_energy.sum(axis=0)
 
         def _total(per_state):
-            # sum(dict.values()) == left fold over DeviceState order
+            # EnergyBreakdown.total_energy_j: left fold over DeviceState order
             tot = np.zeros(per_state.shape[1:])
             for j in range(3):
                 tot = tot + per_state[j]

@@ -441,7 +441,7 @@ def resolve_backend(backend: str) -> str:
     if backend == "auto":
         try:
             import repro.whatif.backend  # noqa: F401  (probe only)
-        except Exception:
+        except ImportError:
             return "numpy"
         return "jax"
     if backend not in ("numpy", "jax"):
@@ -520,12 +520,15 @@ def _evaluate_outcomes_impl(
     bit-identical across backends, energies/penalties <= 1e-9 relative
     (tests/test_whatif_backend.py).
 
-    Degradation ladder: a jax-backend failure (missing toolchain at call
-    time, device loss, a kernel error) is not fatal — it is counted as a
-    ``jax -> numpy`` fallback and the same configs replay through the
-    NumPy compact kernel, which itself degrades ``compact -> row`` on an
-    IR-unsupported store. The NumPy oracle contract makes every rung
-    result-equivalent, so degradations change latency, never answers.
+    Degradation ladder: only declared conditions step down, and each is
+    counted. Configs the IR cannot host replay on the row path; a store
+    the IR cannot hold (irregular sampling) degrades ``compact -> row``;
+    a device out of memory is counted as a ``jax -> numpy`` fallback and
+    the same configs replay through the NumPy compact kernel. Any other
+    error from the jax backend (a compile or programming error) raises,
+    so ``backend="jax"`` never runs NumPy unseen. The NumPy oracle
+    contract makes every rung result-equivalent, so degradations change
+    latency, never answers.
     """
     configs = list(configs)
     replayer_kwargs = replayer_kwargs or {}
@@ -557,13 +560,15 @@ def _evaluate_outcomes_impl(
                 ir_kwargs = {k: v for k, v in replayer_kwargs.items()
                              if k in ("platform_of", "min_job_duration_s",
                                       "min_interval_s", "classifier", "dt_s")}
+                from repro.whatif import backend as jax_backend
                 try:
-                    from repro.whatif import backend as jax_backend
                     sup_out, n_rows, n_runs = jax_backend.replay_ir_outcomes(
                         ir_obj, [configs[i] for i in sup], hosts=hosts,
                         dist=dist, **ir_kwargs)
-                except Exception as e:
-                    obs.fallback("jax", "numpy", type(e).__name__)
+                except jax_backend.DeviceError as e:
+                    if "RESOURCE_EXHAUSTED" not in str(e):
+                        raise
+                    obs.fallback("jax", "numpy", "device_oom")
                     sup_out = None
                 if sup_out is not None:
                     obs.counter("repro_replay_configs_total",
@@ -593,7 +598,7 @@ def _evaluate_outcomes_impl(
                             outcomes[i] = _outcome(res)
                         skips = _merge_skips(skips, rest_skips)
                     return outcomes, n_rows, n_runs, skips
-        # nothing for the accelerator to do (or it failed): NumPy kernel
+        # nothing for the accelerator to do (or it ran out of memory)
     results, n_rows, n_runs, skips = _evaluate(
         configs, store, workers=workers, hosts=hosts, mmap=mmap,
         batched=batched, replayer_kwargs=replayer_kwargs, compact=compact,

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.distributed.compat import shard_map
+from repro.distributed.compat import make_mesh, shard_map
 
 from repro.configs import ASSIGNED_ARCHS, get_config, get_smoke_config
 from repro.distributed import sharding as shd
@@ -44,7 +44,7 @@ def test_compressed_psum_matches_exact_sum():
     devs = jax.devices()
     if len(devs) < 2:
         pytest.skip("needs >= 2 host devices (run under dryrun XLA_FLAGS)")
-    mesh = jax.make_mesh((2,), ("pod",))
+    mesh = make_mesh((2,), ("pod",))
     rng = np.random.default_rng(1)
     g = jnp.asarray(rng.normal(0, 1e-3, (2, 512)).astype(np.float32))
 
@@ -68,7 +68,7 @@ def test_moe_ep_matches_dense_oracle():
         pytest.skip("needs >= 2 host devices")
     from repro.models import moe as moe_mod
     cfg = get_smoke_config("granite-moe-3b-a800m")
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     dist = DistContext(mesh=mesh, batch_axes=("data",), model_axis="model")
     key = jax.random.PRNGKey(0)
     p = moe_mod.init_moe_ffn(key, cfg, ep_size=2, n_layers=1)
@@ -95,7 +95,7 @@ def test_checkpoint_elastic_reshard():
     params = api.init_params(jax.random.PRNGKey(0), cfg)
     opt = adamw()
     state = opt.init(params)
-    mesh = jax.make_mesh((2, 1), ("data", "model"))
+    mesh = make_mesh((2, 1), ("data", "model"))
     dist = DistContext(mesh=mesh, batch_axes=("data",), model_axis="model")
     p_specs = shd.param_specs(params, dist)
     with tempfile.TemporaryDirectory() as d:

@@ -154,45 +154,115 @@ def _np_cap_counts(sorted_p, caps):
         for r in range(sp.shape[0])]).astype(np.int32)
 
 
+def _pallas_cap_counts(sorted_p, caps):
+    """The Pallas kernel on the host-built key words of float rows."""
+    return np.asarray(rr.cap_bucket_scan(
+        *rr.order_key_words(sorted_p), *rr.order_key_words(caps),
+        interpret=INTERPRET))
+
+
+def _dispatched_cap_counts(sorted_p, caps):
+    with jax.enable_x64():
+        return np.asarray(rr.cap_bucket_counts(
+            *rr.order_key_words(sorted_p), *rr.order_key_words(caps)))
+
+
 @pytest.mark.parametrize("rows,n,c", [(3, 17, 5), (1, 1, 7), (4, 256, 33),
                                       (2, 64, 1)])
 def test_cap_bucket_scan(rows, n, c):
-    ks = jax.random.split(KEY, 2)
-    sp = jnp.sort(jax.random.normal(ks[0], (rows, n)) * 100.0, axis=1)
-    caps = jax.random.normal(ks[1], (rows, c)) * 100.0
+    rng = np.random.default_rng(rows * 1000 + n + c)
+    sp = np.sort(rng.normal(0.0, 100.0, (rows, n)), axis=1)
+    caps = rng.normal(0.0, 100.0, (rows, c))
     expect = _np_cap_counts(sp, caps)
-    out = rr.cap_bucket_scan(sp, caps, interpret=INTERPRET)
-    np.testing.assert_array_equal(np.asarray(out), expect)
-    np.testing.assert_array_equal(
-        np.asarray(rr.cap_bucket_scan_reference(sp, caps)), expect)
+    np.testing.assert_array_equal(_pallas_cap_counts(sp, caps), expect)
+    with jax.enable_x64():
+        np.testing.assert_array_equal(
+            np.asarray(rr.cap_bucket_scan_reference(jnp.asarray(sp),
+                                                    jnp.asarray(caps))),
+            expect)
 
 
 def test_cap_bucket_scan_ties_and_padding():
     """Exact ties follow ``side="right"`` (p > cap strictly), and -inf
     front-padding — how the replay backend widens ragged power buckets —
     never changes the counts."""
-    sp = jnp.asarray([[1.0, 2.0, 2.0, 2.0, 3.0, 3.0]])
-    caps = jnp.asarray([[0.5, 2.0, 3.0, 4.0, 1.0]])
+    sp = np.asarray([[1.0, 2.0, 2.0, 2.0, 3.0, 3.0]])
+    caps = np.asarray([[0.5, 2.0, 3.0, 4.0, 1.0]])
     expect = np.array([[6, 2, 0, 0, 5]], np.int32)
-    for fn in (lambda a, b: rr.cap_bucket_scan(a, b, interpret=INTERPRET),
-               rr.cap_bucket_scan_reference):
-        np.testing.assert_array_equal(np.asarray(fn(sp, caps)), expect)
-        padded = jnp.concatenate(
-            [jnp.full((1, 5), -jnp.inf, sp.dtype), sp], axis=1)
-        np.testing.assert_array_equal(np.asarray(fn(padded, caps)), expect)
+    padded = np.concatenate([np.full((1, 5), -np.inf), sp], axis=1)
+    for fn in (_pallas_cap_counts, _dispatched_cap_counts):
+        np.testing.assert_array_equal(fn(sp, caps), expect)
+        np.testing.assert_array_equal(fn(padded, caps), expect)
+
+
+def test_cap_bucket_scan_exact_against_reference():
+    """Bit-identical to the float64 reference on the cases a key encoding
+    can get wrong: -inf front padding, caps equal to sample values, signed
+    zeros, negative values, rows holding one real sample, and caps that
+    differ from a sample in the last bit only. Values stay normal: XLA:CPU
+    flushes subnormals to zero in the reference, while the key words order
+    them exactly as NumPy does."""
+    width, c = 9, 11
+    x = 137.25
+    rows = [
+        [-np.inf] * 8 + [250.0],                      # one real sample
+        [-np.inf] * 6 + [-0.0, 0.0, 0.0],
+        [-np.inf] * 4 + [-3.5, -0.0, 0.0, 1e-300, x],
+        [-1e300, -2.0, -2.0, -2.3e-308, 0.0, x,
+         np.nextafter(x, np.inf), 4e5, 1e300],
+        [-np.inf] * 8 + [0.0],                        # one zero sample
+    ]
+    cap_rows = [
+        [250.0, np.nextafter(250.0, 0), np.nextafter(250.0, np.inf), 0.0,
+         -0.0, -np.inf, np.inf, 1e300, -1e300, 249.0, 251.0],
+        [0.0, -0.0, -1e-300, 1e-300, 1.0, -1.0, 0.0, -0.0, np.inf,
+         -np.inf, 5.0],
+        [x, np.nextafter(x, 0), -0.0, 0.0, -3.5, 1e-300, -np.inf, np.inf,
+         -4.0, 200.0, 2.3e-308],
+        [x, np.nextafter(x, np.inf), -2.0, 0.0, -0.0, 1e300, -1e300, 4e5,
+         -1e-300, 3.0, -np.inf],
+        [-0.0, 0.0, 1e-307, -1e-307, 1.0, -1.0, np.inf, -np.inf, 0.0,
+         -0.0, 2.0],
+    ]
+    sp = np.asarray(rows, np.float64)
+    caps = np.asarray(cap_rows, np.float64)
+    assert sp.shape == (5, width) and caps.shape == (5, c)
+    with jax.enable_x64():
+        expect = np.asarray(rr.cap_bucket_scan_reference(jnp.asarray(sp),
+                                                         jnp.asarray(caps)))
+    np.testing.assert_array_equal(expect, _np_cap_counts(sp, caps))
+    np.testing.assert_array_equal(_pallas_cap_counts(sp, caps), expect)
+    np.testing.assert_array_equal(_dispatched_cap_counts(sp, caps), expect)
+
+
+def test_order_key_words_roundtrip_and_order():
+    rng = np.random.default_rng(5)
+    x = np.concatenate([rng.normal(0, 1e3, 500), rng.normal(0, 1e-200, 50),
+                        [-np.inf, np.inf, 0.0, -0.0, 5e-324, -5e-324,
+                         1.7e308, -1.7e308]])
+    hi, lo = rr.order_key_words(x)
+    assert hi.dtype == lo.dtype == np.int32
+    back = rr.key_words_to_f64(hi, lo)
+    np.testing.assert_array_equal(back, x)     # -0.0 == 0.0 compares equal
+    # the lexicographic word order is the float order, ties included
+    order = np.lexsort((lo, hi))
+    np.testing.assert_array_equal(x[order], np.sort(x))
+    with pytest.raises(ValueError, match="NaN"):
+        rr.order_key_words([1.0, np.nan])
 
 
 def test_cap_bucket_counts_dispatcher_and_ops_wrapper():
-    ks = jax.random.split(KEY, 2)
-    sp = jnp.sort(jax.random.normal(ks[0], (5, 40)), axis=1)
-    caps = jax.random.normal(ks[1], (5, 9))
+    """The dispatcher the backend calls matches the NumPy oracle, and its
+    off-TPU branch refuses to run without x64 (it joins int64 keys)."""
+    rng = np.random.default_rng(11)
+    sp = np.sort(rng.normal(size=(5, 40)), axis=1)
+    caps = rng.normal(size=(5, 9))
     expect = _np_cap_counts(sp, caps)
-    np.testing.assert_array_equal(
-        np.asarray(rr.cap_bucket_counts(sp, caps)), expect)
-    np.testing.assert_array_equal(
-        np.asarray(rr.cap_bucket_counts(sp, caps, use_pallas=False)), expect)
-    np.testing.assert_array_equal(
-        np.asarray(ops.cap_bucket_scan(sp, caps)), expect)
+    np.testing.assert_array_equal(_dispatched_cap_counts(sp, caps), expect)
+    if rr.default_interpret():
+        with pytest.raises(ValueError, match="x64"):
+            rr.cap_bucket_counts(*rr.order_key_words(sp),
+                                 *rr.order_key_words(caps))
 
 
 def test_default_interpret_env_override(monkeypatch):

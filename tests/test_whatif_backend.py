@@ -8,6 +8,7 @@ identical Algorithm-1 decision sequences), **energies and penalties agree
 to <= 1e-9 relative** (float summation order differs), and results are
 independent of padding bucket layout and of the config-axis mesh shape.
 """
+import contextlib
 import tempfile
 
 import numpy as np
@@ -16,6 +17,7 @@ from _hyp import given, settings, st
 
 jax = pytest.importorskip("jax")
 
+import repro.obs as obs
 from repro.cluster import generate_cluster
 from repro.core.controller import ControllerConfig, DownscaleMode
 from repro.core.energy import integrate_runs
@@ -58,6 +60,27 @@ def assert_outcomes_equivalent(ref, cmp_, exact_energies=False):
             else:
                 np.testing.assert_allclose(getattr(a, f), getattr(b, f),
                                            rtol=1e-9, atol=1e-9)
+
+
+@contextlib.contextmanager
+def jax_path_counted():
+    """Run the body with obs on, then assert that the jax path replayed
+    it: ``repro_replay_configs_total{path="jax"}`` grew and no ``jax ->
+    numpy`` fallback was counted — equal answers from a silent NumPy run
+    cannot pass."""
+    prev = obs.enabled()
+    obs.enable()
+    jax_before = obs.REGISTRY.total("repro_replay_configs_total", path="jax")
+    fb_before = obs.REGISTRY.total("repro_fallbacks_total", **{"from": "jax"})
+    try:
+        yield
+        assert obs.REGISTRY.total("repro_replay_configs_total",
+                              path="jax") > jax_before
+        assert obs.REGISTRY.total("repro_fallbacks_total",
+                              **{"from": "jax"}) == fb_before
+    finally:
+        if not prev:
+            obs.disable()
 
 
 @pytest.fixture(scope="module")
@@ -106,8 +129,9 @@ def test_jax_matches_oracle_full_families_and_mesh_shapes(store_dir):
     grid = family_grid()
     ref = evaluate(grid, store, compact=True, min_job_duration_s=0.0)
     for dist in (None, B.config_mesh(1), B.config_mesh(4)):
-        out = evaluate(grid, store, backend="jax", dist=dist,
-                       min_job_duration_s=0.0)
+        with jax_path_counted():
+            out = evaluate(grid, store, backend="jax", dist=dist,
+                           min_job_duration_s=0.0)
         assert_outcomes_equivalent(ref, out)
 
 
@@ -117,8 +141,9 @@ def test_jax_matches_oracle_interval_and_duration_variants(store_dir):
     for mjd, mis in ((300.0, 5.0), (0.0, 1.0), (0.0, 10.0)):
         ref = evaluate(grid, store, compact=True, min_job_duration_s=mjd,
                        min_interval_s=mis)
-        out = evaluate(grid, store, backend="jax", min_job_duration_s=mjd,
-                       min_interval_s=mis)
+        with jax_path_counted():
+            out = evaluate(grid, store, backend="jax",
+                           min_job_duration_s=mjd, min_interval_s=mis)
         assert_outcomes_equivalent(ref, out)
 
 
@@ -166,8 +191,9 @@ def test_jax_matches_oracle_random_grid_and_chunking(seed):
                          seed=int(rng.integers(0, 100)),
                          store=store, shard_s=shard_s)
         ref = run_sweep(store, grid, min_job_duration_s=300.0)
-        cmp_ = run_sweep(store, grid, min_job_duration_s=300.0,
-                         backend="jax")
+        with jax_path_counted():
+            cmp_ = run_sweep(store, grid, min_job_duration_s=300.0,
+                             backend="jax")
         assert cmp_.n_rows == ref.n_rows and cmp_.n_runs == ref.n_runs
         assert_outcomes_equivalent(ref.outcomes, cmp_.outcomes)
         assert [o.pareto for o in ref.outcomes] == \
@@ -177,7 +203,8 @@ def test_jax_matches_oracle_random_grid_and_chunking(seed):
 def test_search_jax_matches_numpy_trajectory(store_dir):
     store = _store(store_dir)
     ref = search_frontier(store, min_job_duration_s=0.0)
-    out = search_frontier(store, min_job_duration_s=0.0, backend="jax")
+    with jax_path_counted():
+        out = search_frontier(store, min_job_duration_s=0.0, backend="jax")
     assert out.n_evals == ref.n_evals
     assert out.knee.params == ref.knee.params
     assert np.isclose(out.knee.saved_fraction, ref.knee.saved_fraction,
@@ -312,6 +339,69 @@ def test_backend_validation_errors(store_dir):
         # downscale-then-parking composite is not IR-capable
         B.replay_ir_outcomes(ir, [CompositePolicy((DownscalePolicy(),
                                                    park))])
+
+
+def test_jax_errors_raise_and_only_device_oom_falls_back(store_dir,
+                                                        monkeypatch):
+    """``backend="jax"`` never runs NumPy unseen: a compile or programming
+    error raises through ``evaluate``; only a device out of memory steps
+    down to the NumPy compact kernel, counted as a fallback."""
+    store = _store(store_dir)
+    grid = family_grid()
+    ref = evaluate(grid, store, compact=True, min_job_duration_s=0.0)
+
+    def broken(*args, **kwargs):
+        raise TypeError("kernel bug")
+    monkeypatch.setattr(B, "replay_ir_outcomes", broken)
+    with pytest.raises(TypeError, match="kernel bug"):
+        evaluate(grid, store, backend="jax", min_job_duration_s=0.0)
+
+    def out_of_memory(*args, **kwargs):
+        raise B.DeviceError("RESOURCE_EXHAUSTED: Out of memory allocating")
+    monkeypatch.setattr(B, "replay_ir_outcomes", out_of_memory)
+    prev = obs.enabled()
+    obs.enable()
+    try:
+        before = obs.REGISTRY.total("repro_fallbacks_total",
+                                reason="device_oom")
+        out = evaluate(grid, store, backend="jax", min_job_duration_s=0.0)
+        assert obs.REGISTRY.total("repro_fallbacks_total",
+                              reason="device_oom") == before + 1
+    finally:
+        if not prev:
+            obs.disable()
+    assert_outcomes_equivalent(ref, out, exact_energies=True)
+
+
+def test_pool_tasks_never_import_jax(store_dir, monkeypatch):
+    """One process per chip: every process-pool task (row sweep, IR build,
+    NumPy run replay, analysis) runs in a worker that never imports JAX,
+    so no worker can reach the jax backend or claim the device."""
+    from _pool_probe import probe
+    from repro.telemetry import analyze_store
+    from repro.telemetry import pipeline
+    from repro.whatif import replay_ir
+
+    store = _store(store_dir)
+    grid = family_grid()
+    seen = []
+    real = pipeline.run_supervised
+
+    def probed(fn, tasks, **kwargs):
+        out = real(probe, [(fn, *t) for t in tasks], **kwargs)
+        seen.extend(jax_loaded for _, jax_loaded in out)
+        return [result for result, _ in out]
+    monkeypatch.setattr(pipeline, "run_supervised", probed)
+    with tempfile.TemporaryDirectory() as d:
+        fresh = TelemetryStore(d, shard_format="npy_dir")
+        generate_cluster(n_devices=8, horizon_s=1200, seed=2, store=fresh,
+                         shard_s=600)
+        run_sweep(fresh, grid, workers=2, compact=False,
+                  min_job_duration_s=0.0)
+        ir = build_ir(fresh, ir_config_for(grid), workers=2)
+    replay_ir(ir, grid, workers=2, min_job_duration_s=0.0)
+    analyze_store(store, workers=2, compact=False)
+    assert len(seen) >= 8 and not any(seen)
 
 
 # --------------------------------------------------------------------------- #
