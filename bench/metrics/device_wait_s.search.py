@@ -1,0 +1,8 @@
+"""Waiting for the device programs (``backend.wait`` spans,
+``whatif/backend.py``: ``block_until_ready`` on each jit program's outputs),
+seconds per search. Moves ``search_s``."""
+from bench.readers import per_call
+
+
+def read(rec):
+    return per_call(rec, "backend.wait", "searches")

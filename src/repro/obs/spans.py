@@ -11,6 +11,12 @@ metrics home).
 When observability is disabled, ``span()`` returns a shared no-op context
 manager: one branch, zero allocation — cheap enough to leave in every stage
 of the pipeline permanently.
+
+While JAX is imported, an enabled span also opens a
+``jax.profiler.TraceAnnotation`` of its name, so under a profiler session
+the program's stages appear on the trace's host plane, on the profiler's
+own clock, beside the device's operations. This module never imports JAX
+itself: it looks it up in ``sys.modules``.
 """
 from __future__ import annotations
 
@@ -18,6 +24,7 @@ import dataclasses
 import json
 import os
 import pathlib
+import sys
 import threading
 import time
 from typing import Callable, Sequence
@@ -48,13 +55,24 @@ _TLS = threading.local()
 _ROOT_PARENT: str | None = None
 _SEQ_LOCK = threading.Lock()
 _SEQ = 0
+# this process's id, read once (and again in a forked child): getpid is a
+# system call, and a span needs it twice
+_PID = os.getpid()
+
+
+def _after_fork() -> None:
+    global _PID
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork)
 
 
 def _next_id() -> str:
     global _SEQ
     with _SEQ_LOCK:
         _SEQ += 1
-        return f"{os.getpid():x}-{_SEQ}"
+        return f"{_PID:x}-{_SEQ}"
 
 
 def _stack() -> list:
@@ -82,8 +100,17 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+def _trace_annotation(name: str):
+    """A profiler annotation of ``name`` when JAX is imported, else None."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    return jax.profiler.TraceAnnotation(name)
+
+
 class _Span:
-    __slots__ = ("name", "attrs", "span_id", "parent_id", "_t0", "_t_wall")
+    __slots__ = ("name", "attrs", "span_id", "parent_id", "_t0", "_t_wall",
+                 "_annotation")
 
     def __init__(self, name: str, attrs: dict) -> None:
         self.name = name
@@ -98,17 +125,22 @@ class _Span:
         self.parent_id = stack[-1] if stack else _ROOT_PARENT
         self.span_id = _next_id()
         stack.append(self.span_id)
+        self._annotation = _trace_annotation(self.name)
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t_wall = time.time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         stack = _stack()
         if stack and stack[-1] == self.span_id:
             stack.pop()
         _SPANS.append(SpanRecord(self.span_id, self.parent_id, self.name,
-                                 self._t_wall, dur, os.getpid(), self.attrs))
+                                 self._t_wall, dur, _PID, self.attrs))
         return False
 
 

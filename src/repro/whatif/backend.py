@@ -185,9 +185,12 @@ class PackedBucket:
         """Device copies of the named arrays, transferred on first use and
         cached (repeat sweeps and search rounds must not re-upload the
         packed tensors; what only the host reads is never sent)."""
-        for name in names:
-            if name not in self._jnp:
-                self._jnp[name] = jnp.asarray(self.arrays[name])
+        missing = [name for name in names if name not in self._jnp]
+        if missing:
+            with obs.span("backend.upload") as sp:
+                for name in missing:
+                    self._jnp[name] = jnp.asarray(self.arrays[name])
+                sp.set(bytes=sum(self.arrays[n].nbytes for n in missing))
         return [self._jnp[name] for name in names]
 
 
@@ -481,60 +484,64 @@ def _downscale_kernel(lr_s0, lr_len, lr_busy, lr_valid, lr_trail, cum_res,
     tsf = ts_first[:, None]
     y_row = y[None, :]
 
-    # carry-independent gathers, one vectorized [S, K] pass each
-    e0 = lr_s0 + lr_len
-    res_end = jnp.take_along_axis(cum_res, e0, axis=1)
-    end4 = jnp.take_along_axis(
-        ds_cum, jnp.broadcast_to(e0[:, None, :], (s_dim, 4, k_dim)),
-        axis=2)
-    # last-row timestamp per run, same float expression as StreamIR.ts()
-    ts_last = tsf + dt * (e0 - 1).astype(jnp.float64)
-    can_fire = (lr_valid.T[:, :, None]
-                & (lr_len.T[:, :, None] > trig[None, None, :]))
+    with jax.named_scope("run_ends"):
+        # carry-independent gathers, one vectorized [S, K] pass each
+        e0 = lr_s0 + lr_len
+        res_end = jnp.take_along_axis(cum_res, e0, axis=1)
+        end4 = jnp.take_along_axis(
+            ds_cum, jnp.broadcast_to(e0[:, None, :], (s_dim, 4, k_dim)),
+            axis=2)
+        # last-row timestamp per run, same float expression as StreamIR.ts()
+        ts_last = tsf + dt * (e0 - 1).astype(jnp.float64)
+        can_fire = (lr_valid.T[:, :, None]
+                    & (lr_len.T[:, :, None] > trig[None, None, :]))
 
-    def step(last_busy, xs):
-        busy_k, ts_last_k, can_k = xs
-        t_cd = last_busy + y_row
-        fire = can_k & (ts_last_k[:, None] >= t_cd)
-        return jnp.where(fire, busy_k[:, None], last_busy), (fire, t_cd)
+    with jax.named_scope("cooldown_scan"):
+        def step(last_busy, xs):
+            busy_k, ts_last_k, can_k = xs
+            t_cd = last_busy + y_row
+            fire = can_k & (ts_last_k[:, None] >= t_cd)
+            return jnp.where(fire, busy_k[:, None], last_busy), (fire, t_cd)
 
-    _, (fire, t_cd) = jax.lax.scan(
-        step, jnp.full((s_dim, c_dim), -jnp.inf),
-        (lr_busy.T, ts_last.T, can_fire), unroll=8)
+        _, (fire, t_cd) = jax.lax.scan(
+            step, jnp.full((s_dim, c_dim), -jnp.inf),
+            (lr_busy.T, ts_last.T, can_fire), unroll=8)
 
-    # vectorized trigger-row resolution over the whole [K, S, C] block:
-    # float-predicted crossing, clipped in float space first so the -inf
-    # no-cooldown sentinel never reaches the int cast
-    s0k = lr_s0.T[:, :, None]
-    lnk = lr_len.T[:, :, None]
-    tsf3 = ts_first[None, :, None]
-    # the float prediction is within ~1e-6 of the exact crossing, so a
-    # 4-probe window [floor(rel)-1, floor(rel)+2] provably contains the
-    # searchsorted result (ties shift it by at most one index)
-    rel = (t_cd - tsf3) / dt - s0k.astype(jnp.float64)
-    lo = jnp.clip(jnp.floor(rel) - 1.0, 0.0,
-                  lnk.astype(jnp.float64)).astype(jnp.int64)
-    cnt = jnp.zeros((k_dim, s_dim, c_dim), jnp.int64)
-    for w in range(4):
-        j = (s0k + lo + w).astype(jnp.float64)
-        ts_j = tsf3 + dt * j
-        cnt = cnt + ((lo + w < lnk) & (ts_j < t_cd)).astype(jnp.int64)
-    i_row = jnp.maximum(trig[None, None, :], lo + cnt)
-    gpos = s0k + jnp.where(fire, i_row, 0)
+    with jax.named_scope("trigger_rows"):
+        # vectorized trigger-row resolution over the whole [K, S, C] block:
+        # float-predicted crossing, clipped in float space first so the -inf
+        # no-cooldown sentinel never reaches the int cast
+        s0k = lr_s0.T[:, :, None]
+        lnk = lr_len.T[:, :, None]
+        tsf3 = ts_first[None, :, None]
+        # the float prediction is within ~1e-6 of the exact crossing, so a
+        # 4-probe window [floor(rel)-1, floor(rel)+2] provably contains the
+        # searchsorted result (ties shift it by at most one index)
+        rel = (t_cd - tsf3) / dt - s0k.astype(jnp.float64)
+        lo = jnp.clip(jnp.floor(rel) - 1.0, 0.0,
+                      lnk.astype(jnp.float64)).astype(jnp.int64)
+        cnt = jnp.zeros((k_dim, s_dim, c_dim), jnp.int64)
+        for w in range(4):
+            j = (s0k + lo + w).astype(jnp.float64)
+            ts_j = tsf3 + dt * j
+            cnt = cnt + ((lo + w < lnk) & (ts_j < t_cd)).astype(jnp.int64)
+        i_row = jnp.maximum(trig[None, None, :], lo + cnt)
+        gpos = s0k + jnp.where(fire, i_row, 0)
 
-    # one 2-D gather per prefix plane, each feeding exactly one consumer
-    # chain — a single fused 5-plane gather tempts XLA:CPU into
-    # duplicating the (expensive) gather into every savings fusion
-    idx = jnp.transpose(gpos, (1, 0, 2)).reshape(s_dim, k_dim * c_dim)
-    firesc = jnp.transpose(fire, (1, 0, 2))
+    with jax.named_scope("prefix_gathers"):
+        # one 2-D gather per prefix plane, each feeding exactly one consumer
+        # chain — a single fused 5-plane gather tempts XLA:CPU into
+        # duplicating the (expensive) gather into every savings fusion
+        idx = jnp.transpose(gpos, (1, 0, 2)).reshape(s_dim, k_dim * c_dim)
+        firesc = jnp.transpose(fire, (1, 0, 2))
 
-    n_down = jnp.sum(fire.astype(jnp.int64), axis=0)
-    n_rest = jnp.sum((fire & ~lr_trail.T[:, :, None]).astype(jnp.int64),
-                     axis=0)
-    g_res = jnp.take_along_axis(cum_res, idx, axis=1).reshape(
-        s_dim, k_dim, c_dim)
-    thr = jnp.sum(jnp.where(
-        firesc, res_end[:, :, None] - g_res, 0), axis=1)
+        n_down = jnp.sum(fire.astype(jnp.int64), axis=0)
+        n_rest = jnp.sum((fire & ~lr_trail.T[:, :, None]).astype(jnp.int64),
+                         axis=0)
+        g_res = jnp.take_along_axis(cum_res, idx, axis=1).reshape(
+            s_dim, k_dim, c_dim)
+        thr = jnp.sum(jnp.where(
+            firesc, res_end[:, :, None] - g_res, 0), axis=1)
 
     def saved(plane):
         g = jnp.take_along_axis(ds_cum[:, plane], idx, axis=1).reshape(
@@ -542,9 +549,10 @@ def _downscale_kernel(lr_s0, lr_len, lr_busy, lr_valid, lr_trail, cum_res,
         return jnp.sum(jnp.where(
             firesc, end4[:, plane][:, :, None] - g, 0.0), axis=1)
 
-    return (n_down, n_rest, thr,
-            saved(0), saved(1),   # clocks (MIN, MAX)
-            saved(2), saved(3))   # clocks (MIN, MIN)
+    with jax.named_scope("savings"):
+        # clocks (MIN, MAX) in planes 0-1, (MIN, MIN) in planes 2-3
+        savings = [saved(plane) for plane in range(4)]
+    return (n_down, n_rest, thr, *savings)
 
 
 def _integrate_runs_kernel(state, energy, lengths, min_samples):
@@ -554,23 +562,24 @@ def _integrate_runs_kernel(state, energy, lengths, min_samples):
     per state. Times are exact integer sums (bit-identical to the
     streaming integrator); energies agree to summation order."""
     _mark_trace("integrate")
-    s_dim, r_dim = state.shape
-    prev = jnp.concatenate(
-        [jnp.full((s_dim, 1), -2, state.dtype), state[:, :-1]], axis=1)
-    seg = jnp.cumsum((state != prev).astype(jnp.int64), axis=1) - 1
-    gid = (seg + (jnp.arange(s_dim) * r_dim)[:, None]).reshape(-1)
-    seg_len = jax.ops.segment_sum(lengths.reshape(-1), gid,
-                                  num_segments=s_dim * r_dim)
-    merged = seg_len[gid].reshape(s_dim, r_dim)
-    final = jnp.where((state == _EXEC) & (merged < min_samples),
-                      _ACTIVE, state)
-    times = []
-    energies = []
-    for st in _STATES:
-        m = final == st
-        times.append(jnp.sum(jnp.where(m, lengths, 0), axis=1))
-        energies.append(jnp.sum(jnp.where(m, energy, 0.0), axis=1))
-    return jnp.stack(times, axis=1), jnp.stack(energies, axis=1)
+    with jax.named_scope("integrate_runs"):
+        s_dim, r_dim = state.shape
+        prev = jnp.concatenate(
+            [jnp.full((s_dim, 1), -2, state.dtype), state[:, :-1]], axis=1)
+        seg = jnp.cumsum((state != prev).astype(jnp.int64), axis=1) - 1
+        gid = (seg + (jnp.arange(s_dim) * r_dim)[:, None]).reshape(-1)
+        seg_len = jax.ops.segment_sum(lengths.reshape(-1), gid,
+                                      num_segments=s_dim * r_dim)
+        merged = seg_len[gid].reshape(s_dim, r_dim)
+        final = jnp.where((state == _EXEC) & (merged < min_samples),
+                          _ACTIVE, state)
+        times = []
+        energies = []
+        for st in _STATES:
+            m = final == st
+            times.append(jnp.sum(jnp.where(m, lengths, 0), axis=1))
+            energies.append(jnp.sum(jnp.where(m, energy, 0.0), axis=1))
+        return jnp.stack(times, axis=1), jnp.stack(energies, axis=1)
 
 
 def _powercap_kernel(cap_hi, cap_lo, caps_hi, caps_lo):
@@ -587,9 +596,11 @@ def _powercap_kernel(cap_hi, cap_lo, caps_hi, caps_lo):
         return jnp.broadcast_to(words[:, None, :], (s_dim, n_b, c_dim)
                                 ).reshape(s_dim * n_b, c_dim)
 
-    return cap_bucket_counts(
-        cap_hi.reshape(s_dim * n_b, p_dim), cap_lo.reshape(s_dim * n_b, p_dim),
-        per_row(caps_hi), per_row(caps_lo)).reshape(s_dim, n_b, c_dim)
+    with jax.named_scope("cap_scan"):
+        return cap_bucket_counts(
+            cap_hi.reshape(s_dim * n_b, p_dim),
+            cap_lo.reshape(s_dim * n_b, p_dim),
+            per_row(caps_hi), per_row(caps_lo)).reshape(s_dim, n_b, c_dim)
 
 
 #: compiled-callable cache: (kernel name, mesh, axis) -> jitted fn.
@@ -641,6 +652,23 @@ def _config_pad(n: int, dist: DistContext | None, floor: int = 8) -> int:
 def _pad_cols(a: np.ndarray, c_pad: int, fill) -> np.ndarray:
     out = np.full(a.shape[:-1] + (c_pad,), fill, dtype=a.dtype)
     out[..., :a.shape[-1]] = a
+    return out
+
+
+def _launch(fn, program: str, bucket: int, *args) -> list[np.ndarray]:
+    """One call of a jit program, the wait for its outputs and their copy
+    to the host, each under its own span (``backend.launch``,
+    ``backend.wait``, ``backend.fetch``). Waiting before the copy only
+    splits the device's time from the transfer: results and the order of
+    work are those of a plain ``np.asarray`` of the outputs."""
+    with obs.span("backend.launch", program=program, bucket=bucket):
+        res = fn(*args)
+    with obs.span("backend.wait", program=program):
+        jax.block_until_ready(res)
+    with obs.span("backend.fetch") as sp:
+        out = [np.asarray(a)
+               for a in (res if isinstance(res, tuple) else (res,))]
+        sp.set(bytes=sum(a.nbytes for a in out))
     return out
 
 
@@ -702,7 +730,7 @@ def _parked_mask(pools, devs: np.ndarray) -> np.ndarray:
     return out
 
 
-def _run_downscale_family(packed: PackedIR, batch, dist, dt):
+def _run_downscale_family(packed: PackedIR, batch, dist):
     """Run the downscale kernel over every bucket; returns
     ``(n_down, n_rest, throttled, sav_exec, sav_act)`` as [S, C] host
     arrays (savings in W·samples, exactly the NumPy kernel's units).
@@ -712,35 +740,40 @@ def _run_downscale_family(packed: PackedIR, batch, dist, dt):
     x/y grid swept at both clock modes replays each pair once. The
     kernel prices both modes; this expands pairs back to configs and
     selects the mode's savings planes."""
-    c_real = len(batch.policies)
-    mode_lo = np.array(
-        [p._min_clocks() == (ClockLevel.MIN, ClockLevel.MIN)
-         for p in batch.policies], dtype=bool)
-    pair_key = np.stack(
-        [np.asarray(batch._trig, np.float64), np.asarray(batch._y)], axis=1)
-    _, uniq_idx, pair_of_c = np.unique(
-        pair_key, axis=0, return_index=True, return_inverse=True)
-    pair_of_c = pair_of_c.reshape(-1)
-    p_real = uniq_idx.shape[0]
-    p_pad = _config_pad(p_real, dist)
-    trig = jnp.asarray(_pad_cols(batch._trig[uniq_idx], p_pad,
-                                 _NEVER_TRIGGERS))
-    y = jnp.asarray(_pad_cols(batch._y[uniq_idx], p_pad, 0.0))
+    with obs.span("backend.upload") as sp:
+        mode_lo = np.array(
+            [p._min_clocks() == (ClockLevel.MIN, ClockLevel.MIN)
+             for p in batch.policies], dtype=bool)
+        pair_key = np.stack(
+            [np.asarray(batch._trig, np.float64), np.asarray(batch._y)],
+            axis=1)
+        _, uniq_idx, pair_of_c = np.unique(
+            pair_key, axis=0, return_index=True, return_inverse=True)
+        pair_of_c = pair_of_c.reshape(-1)
+        p_real = uniq_idx.shape[0]
+        p_pad = _config_pad(p_real, dist)
+        trig = jnp.asarray(_pad_cols(batch._trig[uniq_idx], p_pad,
+                                     _NEVER_TRIGGERS))
+        y = jnp.asarray(_pad_cols(batch._y[uniq_idx], p_pad, 0.0))
+        dt = jnp.asarray(packed.dt_s, jnp.float64)
+        sp.set(bytes=trig.nbytes + y.nbytes + dt.nbytes)
     s = packed.n_streams
     outs = [np.zeros((s, p_real), np.int64) for _ in range(3)] + \
            [np.zeros((s, p_real)) for _ in range(4)]
     fn = _get_fn("downscale", dist)
-    for bucket in packed.buckets:
-        res = fn(*bucket.device("lr_s0", "lr_len", "lr_busy", "lr_valid",
-                                "lr_trail", "cum_res", "ds_cum",
-                                "ts_first"), dt, trig, y)
+    for b, bucket in enumerate(packed.buckets):
+        res = _launch(fn, "downscale", b,
+                      *bucket.device("lr_s0", "lr_len", "lr_busy",
+                                     "lr_valid", "lr_trail", "cum_res",
+                                     "ds_cum", "ts_first"), dt, trig, y)
         for dst, arr in zip(outs, res):
-            dst[bucket.idx] = np.asarray(arr)[:, :p_real]
-    nd, nr, th, se_hi, sa_hi, se_lo, sa_lo = outs
-    sel = mode_lo[None, :]
-    return [nd[:, pair_of_c], nr[:, pair_of_c], th[:, pair_of_c],
-            np.where(sel, se_lo[:, pair_of_c], se_hi[:, pair_of_c]),
-            np.where(sel, sa_lo[:, pair_of_c], sa_hi[:, pair_of_c])]
+            dst[bucket.idx] = arr[:, :p_real]
+    with obs.span("backend.expand", family="downscale"):
+        nd, nr, th, se_hi, sa_hi, se_lo, sa_lo = outs
+        sel = mode_lo[None, :]
+        return [nd[:, pair_of_c], nr[:, pair_of_c], th[:, pair_of_c],
+                np.where(sel, se_lo[:, pair_of_c], se_hi[:, pair_of_c]),
+                np.where(sel, sa_lo[:, pair_of_c], sa_hi[:, pair_of_c])]
 
 
 def _park_tables(packed: PackedIR) -> tuple[np.ndarray, np.ndarray]:
@@ -753,10 +786,12 @@ def _park_tables(packed: PackedIR) -> tuple[np.ndarray, np.ndarray]:
         e_out = np.zeros((s, 3))
         fn = _get_fn("integrate", None)
         ms = jnp.asarray(packed.min_samples, jnp.int64)
-        for bucket in packed.buckets:
-            t, e = fn(*bucket.device("pk_state", "pk_energy", "pk_len"), ms)
-            t_out[bucket.idx] = np.asarray(t) * packed.dt_s
-            e_out[bucket.idx] = np.asarray(e) * packed.dt_s
+        for b, bucket in enumerate(packed.buckets):
+            t, e = _launch(fn, "integrate", b,
+                           *bucket.device("pk_state", "pk_energy", "pk_len"),
+                           ms)
+            t_out[bucket.idx] = t * packed.dt_s
+            e_out[bucket.idx] = e * packed.dt_s
         packed.park_time = t_out
         packed.park_energy = e_out
     return packed.park_time, packed.park_energy
@@ -772,24 +807,30 @@ def _run_powercap_family(packed: PackedIR, batch, dist, dt: float):
     emulates float64 and its emulated divide can return a float32
     quotient."""
     c_real = len(batch.policies)
-    c_pad = _config_pad(c_real, dist)
-    caps = batch._fracs[None, :] * packed.tdp[:, None]
-    # padded lanes get the largest key: no sample is above them
-    caps_hi, caps_lo = order_key_words(_pad_cols(caps, c_pad, np.inf))
+    with obs.span("backend.upload") as sp:
+        c_pad = _config_pad(c_real, dist)
+        caps = batch._fracs[None, :] * packed.tdp[:, None]
+        # padded lanes get the largest key: no sample is above them
+        caps_hi, caps_lo = order_key_words(_pad_cols(caps, c_pad, np.inf))
+        caps_dev = [(jnp.asarray(caps_hi[bucket.idx]),
+                     jnp.asarray(caps_lo[bucket.idx]))
+                    for bucket in packed.buckets]
+        sp.set(bytes=caps_hi.nbytes + caps_lo.nbytes)
     k = np.zeros((packed.n_streams, 4, c_real), np.int64)
-    top_at = np.zeros((packed.n_streams, 4, c_real))
     fn = _get_fn("powercap", dist)
-    for bucket in packed.buckets:
-        k_b = np.asarray(fn(*bucket.device("cap_hi", "cap_lo"),
-                            jnp.asarray(caps_hi[bucket.idx]),
-                            jnp.asarray(caps_lo[bucket.idx])))[:, :, :c_real]
-        k[bucket.idx] = k_b
-        top_at[bucket.idx] = np.take_along_axis(bucket.arrays["cap_top"],
-                                                k_b.astype(np.int64), axis=2)
-    e_cf = packed.base_energy[:, :, None] - (
-        top_at[:, :3, :] - k[:, :3, :] * caps[:, None, :]) * dt
-    pen = dt * (top_at[:, 3, :] / np.cbrt(caps) - k[:, 3, :])
-    thr = k[:, 0, :] + k[:, 1, :] + k[:, 2, :]
+    for b, (bucket, caps_b) in enumerate(zip(packed.buckets, caps_dev)):
+        (k_b,) = _launch(fn, "powercap", b,
+                         *bucket.device("cap_hi", "cap_lo"), *caps_b)
+        k[bucket.idx] = k_b[:, :, :c_real]
+    with obs.span("backend.cap_pricing"):
+        top_at = np.zeros((packed.n_streams, 4, c_real))
+        for bucket in packed.buckets:
+            top_at[bucket.idx] = np.take_along_axis(
+                bucket.arrays["cap_top"], k[bucket.idx], axis=2)
+        e_cf = packed.base_energy[:, :, None] - (
+            top_at[:, :3, :] - k[:, :3, :] * caps[:, None, :]) * dt
+        pen = dt * (top_at[:, 3, :] / np.cbrt(caps) - k[:, 3, :])
+        thr = k[:, 0, :] + k[:, 1, :] + k[:, 2, :]
     return e_cf, pen, thr
 
 
@@ -863,70 +904,80 @@ def replay_ir_outcomes(
     downs = np.zeros((s, n_cfg), np.int64)
     thr = np.zeros((s, n_cfg), np.int64)
 
+    # children of the kernels span: backend.upload, backend.launch,
+    # backend.wait, backend.fetch, backend.cap_pricing and backend.expand
     with obs.span("backend.kernels", configs=n_cfg, streams=s), \
          jax.enable_x64():
-        dt_j = jnp.asarray(dt, jnp.float64)
         for batch, idxs in make_batches(policies):
             ci = np.asarray(idxs, dtype=np.int64)
             if isinstance(batch, NoOpBatch):
                 continue
             if isinstance(batch, DownscaleBatch):
                 nd, nr, th, se, sa = _run_downscale_family(
-                    packed, batch, dist, dt_j)
-                cf_energy[:, 1, ci] = packed.base_energy[:, 1:2] - se * dt
-                cf_energy[:, 2, ci] = packed.base_energy[:, 2:3] - sa * dt
-                pen[:, ci] = nr * _price_rows(batch.policies,
-                                              packed.platforms)
-                wakes[:, ci] = nr
-                downs[:, ci] = nd
-                thr[:, ci] = th
+                    packed, batch, dist)
+                with obs.span("backend.expand", family="downscale"):
+                    cf_energy[:, 1, ci] = (packed.base_energy[:, 1:2]
+                                           - se * dt)
+                    cf_energy[:, 2, ci] = (packed.base_energy[:, 2:3]
+                                           - sa * dt)
+                    pen[:, ci] = nr * _price_rows(batch.policies,
+                                                  packed.platforms)
+                    wakes[:, ci] = nr
+                    downs[:, ci] = nd
+                    thr[:, ci] = th
             elif isinstance(batch, ParkingBatch):
                 pt, pe = _park_tables(packed)
-                mask = _parked_mask(batch._pools, packed.devs)
-                m3 = mask[:, None, :]
-                cf_time[:, :, ci] = np.where(m3, pt[:, :, None],
-                                             packed.base_time[:, :, None])
-                cf_energy[:, :, ci] = np.where(m3, pe[:, :, None],
-                                               packed.base_energy[:, :, None])
-                wk = np.where(mask, packed.pk_wakes[:, None], 0)
-                wakes[:, ci] = wk
-                thr[:, ci] = np.where(mask, packed.pk_idle[:, None], 0)
-                pen[:, ci] = wk * np.array(
-                    [p.resume_latency_s for p in batch.policies])[None, :]
+                with obs.span("backend.expand", family="parking"):
+                    mask = _parked_mask(batch._pools, packed.devs)
+                    m3 = mask[:, None, :]
+                    cf_time[:, :, ci] = np.where(
+                        m3, pt[:, :, None], packed.base_time[:, :, None])
+                    cf_energy[:, :, ci] = np.where(
+                        m3, pe[:, :, None], packed.base_energy[:, :, None])
+                    wk = np.where(mask, packed.pk_wakes[:, None], 0)
+                    wakes[:, ci] = wk
+                    thr[:, ci] = np.where(mask, packed.pk_idle[:, None], 0)
+                    pen[:, ci] = wk * np.array(
+                        [p.resume_latency_s for p in batch.policies])[None, :]
             elif isinstance(batch, PowerCapBatch):
                 e_cf, p_cap, th = _run_powercap_family(
                     packed, batch, dist, dt)
-                cf_energy[:, :, ci] = e_cf
-                pen[:, ci] = p_cap
-                thr[:, ci] = th
+                with obs.span("backend.expand", family="powercap"):
+                    cf_energy[:, :, ci] = e_cf
+                    pen[:, ci] = p_cap
+                    thr[:, ci] = th
             elif isinstance(batch, CompositeBatch):
                 if not batch._ir_ok:
                     raise ValueError(
                         "run-level replay supports only parking+downscale "
                         "composites; route this batch through the row path")
                 nd, nr, th_ds, se, sa = _run_downscale_family(
-                    packed, batch._ds_batch, dist, dt_j)
+                    packed, batch._ds_batch, dist)
                 pt, pe = _park_tables(packed)
-                mask = _parked_mask(batch._park_pools, packed.devs)
-                m3 = mask[:, None, :]
-                ds_e = np.repeat(packed.base_energy[:, :, None],
-                                 len(idxs), axis=2)
-                ds_e[:, 1, :] -= se * dt
-                ds_e[:, 2, :] -= sa * dt
-                cf_time[:, :, ci] = np.where(m3, pt[:, :, None],
-                                             packed.base_time[:, :, None])
-                cf_energy[:, :, ci] = np.where(m3, pe[:, :, None], ds_e)
-                wk = np.where(mask, packed.pk_wakes[:, None], 0)
-                wakes[:, ci] = wk + nr
-                downs[:, ci] = nd
-                thr[:, ci] = np.where(mask, packed.pk_idle[:, None], th_ds)
-                price_park = np.array(
-                    [p.parts[0].resume_latency_s for p in batch.policies])
-                price_ds = _price_rows(
-                    [p.parts[1] for p in batch.policies], packed.platforms)
-                # matches price_events' per-channel left fold:
-                # fl(fl(wakes*price0) + fl(restores*price1))
-                pen[:, ci] = wk * price_park[None, :] + nr * price_ds
+                with obs.span("backend.expand", family="composite"):
+                    mask = _parked_mask(batch._park_pools, packed.devs)
+                    m3 = mask[:, None, :]
+                    ds_e = np.repeat(packed.base_energy[:, :, None],
+                                     len(idxs), axis=2)
+                    ds_e[:, 1, :] -= se * dt
+                    ds_e[:, 2, :] -= sa * dt
+                    cf_time[:, :, ci] = np.where(
+                        m3, pt[:, :, None], packed.base_time[:, :, None])
+                    cf_energy[:, :, ci] = np.where(m3, pe[:, :, None], ds_e)
+                    wk = np.where(mask, packed.pk_wakes[:, None], 0)
+                    wakes[:, ci] = wk + nr
+                    downs[:, ci] = nd
+                    thr[:, ci] = np.where(mask, packed.pk_idle[:, None],
+                                          th_ds)
+                    price_park = np.array(
+                        [p.parts[0].resume_latency_s
+                         for p in batch.policies])
+                    price_ds = _price_rows(
+                        [p.parts[1] for p in batch.policies],
+                        packed.platforms)
+                    # matches price_events' per-channel left fold:
+                    # fl(fl(wakes*price0) + fl(restores*price1))
+                    pen[:, ci] = wk * price_park[None, :] + nr * price_ds
             else:
                 raise ValueError(
                     f"jax backend supports only IR-capable policy families, "

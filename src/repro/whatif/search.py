@@ -34,9 +34,10 @@ Typical use::
     print(format_frontier(result.frontier, top=10))
 
 Observability: the search runs under a ``whatif.search`` span with one
-``search.round`` child per refinement round, and records per-round evals,
-knee movement, budget consumption and warm-seed hits as ``repro_search_*``
-metrics when :mod:`repro.obs` is enabled. Independently of obs, every
+``search.round`` child per refinement round (each Pareto pass is a
+``whatif.pareto`` span, each knee a ``search.find_knee`` span), and records
+per-round evals, knee movement, budget consumption and warm-seed hits as
+``repro_search_*`` metrics when :mod:`repro.obs` is enabled. Independently of obs, every
 search emits a deterministic eval-by-eval convergence trace in
 ``result.frontier.trace`` (see :class:`repro.whatif.sweep.Frontier`).
 """
@@ -331,29 +332,30 @@ def find_knee(outcomes: Sequence[PolicyOutcome]) -> PolicyOutcome:
     """
     if not outcomes:
         raise ValueError("find_knee requires at least one outcome")
-    flags = pareto_flags([o.energy_saved_j for o in outcomes],
-                         [o.penalty_s for o in outcomes])
-    front = [o for o, f in zip(outcomes, flags) if f]
-    front.sort(key=lambda o: (o.penalty_s, -o.energy_saved_j))
-    norm = _normalizer(front)
-    if len(front) >= 3:
-        (s0, p0), (s1, p1) = norm(front[0]), norm(front[-1])
-        ds, dp = s1 - s0, p1 - p0
-        chord = math.hypot(ds, dp)
-        if chord > 0:
-            best_i, best_d = 0, -math.inf
-            for i, o in enumerate(front):
-                s, p = norm(o)
-                d = (dp * (s - s0) - ds * (p - p0)) / chord
-                if d > best_d + 1e-12:
-                    best_i, best_d = i, d
-            return front[best_i]
-    best_i, best_u = 0, -math.inf
-    for i, o in enumerate(front):
-        s, p = norm(o)
-        if s - p > best_u + 1e-12:
-            best_i, best_u = i, s - p
-    return front[best_i]
+    with obs.span("search.find_knee", n=len(outcomes)):
+        flags = pareto_flags([o.energy_saved_j for o in outcomes],
+                             [o.penalty_s for o in outcomes])
+        front = [o for o, f in zip(outcomes, flags) if f]
+        front.sort(key=lambda o: (o.penalty_s, -o.energy_saved_j))
+        norm = _normalizer(front)
+        if len(front) >= 3:
+            (s0, p0), (s1, p1) = norm(front[0]), norm(front[-1])
+            ds, dp = s1 - s0, p1 - p0
+            chord = math.hypot(ds, dp)
+            if chord > 0:
+                best_i, best_d = 0, -math.inf
+                for i, o in enumerate(front):
+                    s, p = norm(o)
+                    d = (dp * (s - s0) - ds * (p - p0)) / chord
+                    if d > best_d + 1e-12:
+                        best_i, best_d = i, d
+                return front[best_i]
+        best_i, best_u = 0, -math.inf
+        for i, o in enumerate(front):
+            s, p = norm(o)
+            if s - p > best_u + 1e-12:
+                best_i, best_u = i, s - p
+        return front[best_i]
 
 
 def achievable_saving(outcomes: Iterable[PolicyOutcome],

@@ -173,11 +173,13 @@ class Frontier:
 def pareto_flags(saved: Sequence[float], penalty: Sequence[float]) -> list[bool]:
     """Non-dominated points for (maximize saved, minimize penalty)."""
     flags = []
-    for i, (s_i, p_i) in enumerate(zip(saved, penalty)):
-        dominated = any(
-            (s_j >= s_i and p_j <= p_i) and (s_j > s_i or p_j < p_i)
-            for j, (s_j, p_j) in enumerate(zip(saved, penalty)) if j != i)
-        flags.append(not dominated)
+    with obs.span("whatif.pareto", n=len(saved)):
+        for i, (s_i, p_i) in enumerate(zip(saved, penalty)):
+            dominated = any(
+                (s_j >= s_i and p_j <= p_i) and (s_j > s_i or p_j < p_i)
+                for j, (s_j, p_j) in enumerate(zip(saved, penalty))
+                if j != i)
+            flags.append(not dominated)
     return flags
 
 
@@ -467,8 +469,8 @@ def _evaluate_outcomes(
     fault=None,
 ) -> tuple[list[PolicyOutcome], int, int, list[dict]]:
     """Observability wrapper around :func:`_evaluate_outcomes_impl`: every
-    evaluate call runs under a ``whatif.evaluate`` span, with per-family
-    config counts and a throughput gauge recorded when :mod:`repro.obs` is
+    evaluate call runs under a ``whatif.evaluate`` span, with its wall time
+    and per-family config counts recorded when :mod:`repro.obs` is
     enabled. Pure pass-through otherwise — outcomes are bit-identical with
     obs on or off."""
     configs = list(configs)
@@ -480,11 +482,8 @@ def _evaluate_outcomes(
             compact=compact, ir=ir, backend=backend, dist=dist,
             strict=strict, verify=verify, fault=fault)
     if obs.enabled():
-        dt = max(time.perf_counter() - t0, 1e-12)
-        obs.observe("repro_replay_seconds", dt,
+        obs.observe("repro_replay_seconds", time.perf_counter() - t0,
                     help="wall time of evaluate calls")
-        obs.gauge("repro_replay_configs_per_s", len(configs) / dt,
-                  help="config throughput of the last evaluate")
         for fam, n in collections.Counter(p.name for p in configs).items():
             obs.counter("repro_replay_family_configs_total", float(n),
                         family=fam,

@@ -15,6 +15,7 @@ from repro.telemetry import TelemetryStore
 from repro.telemetry.pipeline import analyze_store
 from repro.whatif import (default_policy_grid, frontier_to_dict, run_sweep,
                           search_frontier)
+from repro.whatif.search import find_knee
 
 
 @pytest.fixture(scope="module")
@@ -168,6 +169,84 @@ def test_sweep_and_search_bit_identical_obs_on_off(store_dir, clean_obs):
     assert r_on.frontier.trace and r_off.frontier.trace
 
 
+def test_jax_sweep_and_search_bit_identical_obs_on_off(store_dir, clean_obs,
+                                                     tmp_path):
+    """The backend's spans, and their profiler annotations under a
+    profiler session, leave every answer of the jax path as it is."""
+    import jax
+
+    store = TelemetryStore(store_dir)
+    grid = default_policy_grid(dense=False)
+
+    def answers():
+        front = run_sweep(store, grid, backend="jax", min_job_duration_s=0.0)
+        res = search_frontier(store, max_evals=40, backend="jax",
+                              min_job_duration_s=0.0)
+        return (frontier_to_dict(front), find_knee(front.outcomes).params,
+                frontier_to_dict(res.frontier), res.knee.params)
+
+    off = answers()
+    obs.enable()
+    on = answers()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        traced = answers()
+    finally:
+        jax.profiler.stop_trace()
+    assert on == off and traced == off
+    assert BACKEND_CHILDREN | {"whatif.pareto", "search.find_knee"} <= \
+        {s.name for s in obs.spans()}
+
+
+#: the spans that split the backend's ``backend.kernels`` span
+BACKEND_CHILDREN = {"backend.upload", "backend.launch", "backend.wait",
+                    "backend.fetch", "backend.cap_pricing", "backend.expand"}
+
+
+def test_jax_sweep_spans_name_each_step(store_dir, clean_obs):
+    obs.enable()
+    store = TelemetryStore(store_dir)
+    front = run_sweep(store, default_policy_grid(dense=False), backend="jax",
+                      min_job_duration_s=0.0)
+    find_knee(front.outcomes)
+    recs = obs.spans()
+    by_id = {r.span_id: r for r in recs}
+    by_name = {}
+    for r in recs:
+        by_name.setdefault(r.name, []).append(r)
+    # one Pareto pass for the frontier, one for the knee
+    pareto = by_name["whatif.pareto"]
+    assert len(pareto) == 2
+    assert all(p.attrs == {"n": len(front.outcomes)} for p in pareto)
+    (knee,) = by_name["search.find_knee"]
+    assert knee.attrs == {"n": len(front.outcomes)}
+    assert by_id[pareto[1].parent_id] is knee
+    # every step of the device programs is a child of the kernels span
+    (kernels,) = by_name["backend.kernels"]
+    assert BACKEND_CHILDREN <= set(by_name)
+    for name in BACKEND_CHILDREN:
+        assert all(r.parent_id == kernels.span_id for r in by_name[name])
+    # the parking tables are priced once per packed IR, maybe by an
+    # earlier test
+    launches = by_name["backend.launch"]
+    assert {"downscale", "powercap"} <= {r.attrs["program"] for r in launches}
+    assert {r.attrs["program"] for r in launches} <= \
+        {"downscale", "powercap", "integrate"}
+    for r in launches:
+        assert set(r.attrs) == {"program", "bucket"}
+        assert isinstance(r.attrs["bucket"], int)
+    waits = by_name["backend.wait"]
+    assert len(waits) == len(launches) == len(by_name["backend.fetch"])
+    assert all(set(r.attrs) == {"program"} for r in waits)
+    for name in ("backend.fetch", "backend.upload"):
+        assert all(r.attrs["bytes"] > 0 for r in by_name[name])
+    assert {r.attrs["family"] for r in by_name["backend.expand"]} == \
+        {"downscale", "parking", "powercap"}
+    # the children cover the kernels span, less the host's loop around them
+    covered = sum(r.dur_s for n in BACKEND_CHILDREN for r in by_name[n])
+    assert covered <= kernels.dur_s
+
+
 def test_search_trace_is_deterministic_replay_data(store_dir, clean_obs):
     store = TelemetryStore(store_dir)
     res = search_frontier(store, max_evals=40, min_job_duration_s=0.0)
@@ -184,9 +263,13 @@ def test_search_trace_is_deterministic_replay_data(store_dir, clean_obs):
 # --------------------------------------------------------------------------- #
 # acceptance gate: the instrumented pipeline emits a wide metric surface
 # --------------------------------------------------------------------------- #
-def test_pipeline_emits_at_least_15_repro_metrics(store_dir, clean_obs):
+def test_pipeline_emits_at_least_15_repro_metrics(tmp_path, clean_obs):
+    # a store of its own, so the gate counts the cold pipeline (IR build
+    # included) whichever tests of this module ran before it
+    store = TelemetryStore(tmp_path)
+    generate_cluster(n_devices=8, horizon_s=2700, seed=3, store=store,
+                     shard_s=900)
     obs.enable()
-    store = TelemetryStore(store_dir)
     analyze_store(store)
     run_sweep(store, default_policy_grid(dense=False)[:10],
               min_job_duration_s=0.0)
