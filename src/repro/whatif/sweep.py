@@ -171,16 +171,35 @@ class Frontier:
 
 
 def pareto_flags(saved: Sequence[float], penalty: Sequence[float]) -> list[bool]:
-    """Non-dominated points for (maximize saved, minimize penalty)."""
-    flags = []
+    """Non-dominated points for (maximize saved, minimize penalty).
+
+    Point ``j`` dominates ``i`` iff ``saved[j] >= saved[i]`` and
+    ``penalty[j] <= penalty[i]``, one of them strictly. So exact duplicates
+    are both kept, ``-0.0`` equals ``0.0``, and a point with NaN in either
+    coordinate is never dominated and never dominates. One lexicographic
+    sort (penalty ascending, then saving descending) and a running maximum
+    of saving over lower penalties: O(n log n), comparisons only, so the
+    flags are exact.
+    """
     with obs.span("whatif.pareto", n=len(saved)):
-        for i, (s_i, p_i) in enumerate(zip(saved, penalty)):
-            dominated = any(
-                (s_j >= s_i and p_j <= p_i) and (s_j > s_i or p_j < p_i)
-                for j, (s_j, p_j) in enumerate(zip(saved, penalty))
-                if j != i)
-            flags.append(not dominated)
-    return flags
+        s = np.asarray(saved, dtype=np.float64)
+        p = np.asarray(penalty, dtype=np.float64)
+        flags = np.ones(len(s), dtype=bool)
+        order = np.flatnonzero(~(np.isnan(s) | np.isnan(p)))
+        if order.size:
+            order = order[np.lexsort((-s[order], p[order]))]
+            so, po = s[order], p[order]
+            first = np.concatenate(([True], po[1:] != po[:-1]))
+            group = np.cumsum(first) - 1
+            best = so[first]  # each equal-penalty group's highest saving
+            dominated = so < best[group]
+            # any strictly lower penalty with a saving as high; the first
+            # group has none (a running maximum from -inf would mark a
+            # -inf saving there dominated)
+            below = np.maximum.accumulate(best)[np.maximum(group - 1, 0)]
+            dominated |= (group > 0) & (below >= so)
+            flags[order] = ~dominated
+    return flags.tolist()
 
 
 def assemble_frontier(outcomes: Sequence[PolicyOutcome],
