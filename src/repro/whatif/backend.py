@@ -17,7 +17,9 @@ deadline-aware sweeps of arXiv 2004.08177-style studies — are routine:
   ``shard_map`` over a :class:`repro.distributed.context.DistContext`
   mesh (:func:`config_mesh`), so multi-device scales near-linearly —
   every per-config op is elementwise along the axis, so sharding needs no
-  cross-device communication at all;
+  cross-device communication at all. Every chip of the mesh holds the
+  packed tables whole, placed once per mesh; the config-axis arrays go
+  straight to their shards (:func:`_place`);
 * the PowerCap sorted-power cap-bucket scan runs through
   :func:`repro.kernels.run_replay.cap_bucket_counts` — the Pallas kernel
   on TPU, the vmapped ``searchsorted`` reference elsewhere. It compares
@@ -55,7 +57,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import repro.obs as obs
 from repro.core.energy import EnergyBreakdown
@@ -132,6 +134,15 @@ def _pow2(n: int, floor: int) -> int:
     return max(int(floor), 1 << max(int(n) - 1, 0).bit_length())
 
 
+#: bytes put on chips by :func:`_place`, once per chip copy, and config
+#: lanes launched beyond the real count by :func:`_config_pad`
+PLACED_BYTES = ("repro_backend_placed_bytes_total",
+                "bytes put on chips, once per chip copy, labelled {devices}")
+PAD_CONFIGS = ("repro_backend_pad_configs_total",
+               "config lanes launched beyond the real count, labelled "
+               "{family}")
+
+
 # --------------------------------------------------------------------------- #
 # Mesh helper
 # --------------------------------------------------------------------------- #
@@ -148,6 +159,34 @@ def config_mesh(n_devices: int | None = None,
     n = len(devs) if n_devices is None else min(int(n_devices), len(devs))
     return DistContext(mesh=Mesh(np.array(devs[:n]), (axis,)),
                        batch_axes=(axis,))
+
+
+def _mesh_of(dist: DistContext | None) -> Mesh | None:
+    """The mesh the config axis runs over, or None on one device."""
+    return dist.mesh if dist is not None and dist.enabled else None
+
+
+def _place(hosts: Sequence[np.ndarray], dist: DistContext | None = None,
+           config_axis: int | None = None) -> tuple[list[jax.Array], int]:
+    """Every host-to-device transfer of the backend: ``jnp.asarray`` on the
+    default device without a mesh; with one, each array split over the
+    mesh along ``config_axis``, or replicated on every chip when it is
+    None. Returns the device arrays and the bytes put on chips, counted
+    once per chip copy (``repro_backend_placed_bytes_total``)."""
+    mesh = _mesh_of(dist)
+    if mesh is None:
+        out = [jnp.asarray(a) for a in hosts]
+        nbytes = sum(a.nbytes for a in out)
+    else:
+        spec = (P() if config_axis is None
+                else P(*(None,) * config_axis, dist.batch_axes[0]))
+        sh = NamedSharding(mesh, spec)
+        out = [jax.device_put(a, sh) for a in hosts]
+        nbytes = sum(int(np.prod(sh.shard_shape(a.shape))) * a.dtype.itemsize
+                     for a in out) * mesh.size
+    obs.counter(PLACED_BYTES[0], float(nbytes), help=PLACED_BYTES[1],
+                devices=1 if mesh is None else mesh.size)
+    return out, nbytes
 
 
 # --------------------------------------------------------------------------- #
@@ -179,19 +218,25 @@ class PackedBucket:
     key: tuple[int, int, int, int]
     idx: np.ndarray                  # [S_b] positions in the packed stream list
     arrays: dict[str, np.ndarray]
-    _jnp: dict[str, jax.Array] = dataclasses.field(default_factory=dict)
+    #: device copies by (name, mesh); the mesh is None on one device
+    _jnp: dict[tuple, jax.Array] = dataclasses.field(default_factory=dict)
 
-    def device(self, *names: str) -> list[jax.Array]:
-        """Device copies of the named arrays, transferred on first use and
-        cached (repeat sweeps and search rounds must not re-upload the
-        packed tensors; what only the host reads is never sent)."""
-        missing = [name for name in names if name not in self._jnp]
+    def device(self, *names: str,
+               dist: DistContext | None = None) -> list[jax.Array]:
+        """Device copies of the named arrays, placed on first use and
+        cached per mesh (repeat sweeps and search rounds must not re-upload
+        the packed tensors; what only the host reads is never sent). Under
+        a mesh every chip holds the whole of each array."""
+        mesh = _mesh_of(dist)
+        missing = [name for name in names if (name, mesh) not in self._jnp]
         if missing:
             with obs.span("backend.upload") as sp:
-                for name in missing:
-                    self._jnp[name] = jnp.asarray(self.arrays[name])
-                sp.set(bytes=sum(self.arrays[n].nbytes for n in missing))
-        return [self._jnp[name] for name in names]
+                placed, nbytes = _place([self.arrays[n] for n in missing],
+                                        dist)
+                self._jnp.update(((n, mesh), a)
+                                 for n, a in zip(missing, placed))
+                sp.set(bytes=nbytes)
+        return [self._jnp[(name, mesh)] for name in names]
 
 
 @dataclasses.dataclass
@@ -637,15 +682,19 @@ def _get_fn(name: str, dist: DistContext | None):
     return fn
 
 
-def _config_pad(n: int, dist: DistContext | None, floor: int = 8) -> int:
+def _config_pad(n: int, dist: DistContext | None, family: str,
+                floor: int = 8) -> int:
     """Pad the config axis to a power of two (>= ``floor``) so search
     rounds with drifting candidate counts reuse compilations, rounded up
     to the mesh axis size (shard_map needs exact divisibility — same
-    rule as :mod:`repro.distributed.sharding`)."""
+    rule as :mod:`repro.distributed.sharding`). The lanes added are
+    counted per ``family`` (``repro_backend_pad_configs_total``)."""
     c = _pow2(n, floor)
     if dist is not None and dist.enabled:
         ax = int(dist.mesh.shape[dist.batch_axes[0]])
         c = ((c + ax - 1) // ax) * ax
+    obs.counter(PAD_CONFIGS[0], float(c - n), help=PAD_CONFIGS[1],
+                family=family)
     return c
 
 
@@ -686,13 +735,12 @@ def jax_integrate_runs(states: np.ndarray, energy: np.ndarray,
     c, r = energy.shape
     with jax.enable_x64():
         fn = _get_fn("integrate", None)
-        t, e = fn(
-            jnp.asarray(np.broadcast_to(
-                np.asarray(states, np.int32)[None, :], (c, r))),
-            jnp.asarray(energy),
-            jnp.asarray(np.broadcast_to(
-                np.asarray(lengths, np.int64)[None, :], (c, r))),
-            jnp.asarray(int(min_samples), jnp.int64))
+        args, _ = _place([
+            np.broadcast_to(np.asarray(states, np.int32)[None, :], (c, r)),
+            energy,
+            np.broadcast_to(np.asarray(lengths, np.int64)[None, :], (c, r)),
+            np.int64(min_samples)])
+        t, e = fn(*args)
         t = np.asarray(t)
         e = np.asarray(e)
     return [
@@ -751,12 +799,12 @@ def _run_downscale_family(packed: PackedIR, batch, dist):
             pair_key, axis=0, return_index=True, return_inverse=True)
         pair_of_c = pair_of_c.reshape(-1)
         p_real = uniq_idx.shape[0]
-        p_pad = _config_pad(p_real, dist)
-        trig = jnp.asarray(_pad_cols(batch._trig[uniq_idx], p_pad,
-                                     _NEVER_TRIGGERS))
-        y = jnp.asarray(_pad_cols(batch._y[uniq_idx], p_pad, 0.0))
-        dt = jnp.asarray(packed.dt_s, jnp.float64)
-        sp.set(bytes=trig.nbytes + y.nbytes + dt.nbytes)
+        p_pad = _config_pad(p_real, dist, "downscale")
+        (trig, y), cfg_bytes = _place(
+            [_pad_cols(batch._trig[uniq_idx], p_pad, _NEVER_TRIGGERS),
+             _pad_cols(batch._y[uniq_idx], p_pad, 0.0)], dist, config_axis=0)
+        (dt,), dt_bytes = _place([np.float64(packed.dt_s)], dist)
+        sp.set(bytes=cfg_bytes + dt_bytes)
     s = packed.n_streams
     outs = [np.zeros((s, p_real), np.int64) for _ in range(3)] + \
            [np.zeros((s, p_real)) for _ in range(4)]
@@ -765,7 +813,8 @@ def _run_downscale_family(packed: PackedIR, batch, dist):
         res = _launch(fn, "downscale", b,
                       *bucket.device("lr_s0", "lr_len", "lr_busy",
                                      "lr_valid", "lr_trail", "cum_res",
-                                     "ds_cum", "ts_first"), dt, trig, y)
+                                     "ds_cum", "ts_first", dist=dist),
+                      dt, trig, y)
         for dst, arr in zip(outs, res):
             dst[bucket.idx] = arr[:, :p_real]
     with obs.span("backend.expand", family="downscale"):
@@ -785,7 +834,7 @@ def _park_tables(packed: PackedIR) -> tuple[np.ndarray, np.ndarray]:
         t_out = np.zeros((s, 3))
         e_out = np.zeros((s, 3))
         fn = _get_fn("integrate", None)
-        ms = jnp.asarray(packed.min_samples, jnp.int64)
+        (ms,), _ = _place([np.int64(packed.min_samples)])
         for b, bucket in enumerate(packed.buckets):
             t, e = _launch(fn, "integrate", b,
                            *bucket.device("pk_state", "pk_energy", "pk_len"),
@@ -808,19 +857,20 @@ def _run_powercap_family(packed: PackedIR, batch, dist, dt: float):
     quotient."""
     c_real = len(batch.policies)
     with obs.span("backend.upload") as sp:
-        c_pad = _config_pad(c_real, dist)
+        c_pad = _config_pad(c_real, dist, "powercap")
         caps = batch._fracs[None, :] * packed.tdp[:, None]
         # padded lanes get the largest key: no sample is above them
         caps_hi, caps_lo = order_key_words(_pad_cols(caps, c_pad, np.inf))
-        caps_dev = [(jnp.asarray(caps_hi[bucket.idx]),
-                     jnp.asarray(caps_lo[bucket.idx]))
-                    for bucket in packed.buckets]
-        sp.set(bytes=caps_hi.nbytes + caps_lo.nbytes)
+        placed = [_place([caps_hi[bucket.idx], caps_lo[bucket.idx]], dist,
+                         config_axis=1) for bucket in packed.buckets]
+        caps_dev = [words for words, _ in placed]
+        sp.set(bytes=sum(nbytes for _, nbytes in placed))
     k = np.zeros((packed.n_streams, 4, c_real), np.int64)
     fn = _get_fn("powercap", dist)
     for b, (bucket, caps_b) in enumerate(zip(packed.buckets, caps_dev)):
         (k_b,) = _launch(fn, "powercap", b,
-                         *bucket.device("cap_hi", "cap_lo"), *caps_b)
+                         *bucket.device("cap_hi", "cap_lo", dist=dist),
+                         *caps_b)
         k[bucket.idx] = k_b[:, :, :c_real]
     with obs.span("backend.cap_pricing"):
         top_at = np.zeros((packed.n_streams, 4, c_real))
@@ -890,11 +940,13 @@ def replay_ir_outcomes(
     dt = dt_s
 
     if obs.enabled():
-        n_dev = (dist.mesh.size if dist is not None and dist.mesh is not None
-                 else len(jax.devices()))
-        obs.gauge("repro_backend_devices", float(n_dev),
+        mesh = _mesh_of(dist)
+        obs.gauge("repro_backend_devices",
+                  float(1 if mesh is None else mesh.size),
                   help="devices the config axis runs over (mesh size when "
-                       "sharded, visible devices otherwise)")
+                       "sharded, 1 otherwise)")
+        for name, help_text in (PLACED_BYTES, PAD_CONFIGS):
+            obs.REGISTRY.counter(name, help_text)
 
     # per-(stream, config) accumulators, initialised to the baseline
     cf_time = np.repeat(packed.base_time[:, :, None], n_cfg, axis=2)
