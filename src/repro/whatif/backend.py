@@ -51,6 +51,7 @@ from __future__ import annotations
 
 import collections.abc
 import dataclasses
+import math
 from typing import Iterable, Mapping, Sequence
 
 import numpy as np
@@ -682,17 +683,33 @@ def _get_fn(name: str, dist: DistContext | None):
     return fn
 
 
+#: the TPU's lane width: the config axis is the minor axis of every
+#: ``[.., C]`` block, whose minor tile the hardware fills to this many lanes
+_LANES = 128
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def _config_pad(n: int, dist: DistContext | None, family: str,
                 floor: int = 8) -> int:
-    """Pad the config axis to a power of two (>= ``floor``) so search
-    rounds with drifting candidate counts reuse compilations, rounded up
-    to the mesh axis size (shard_map needs exact divisibility — same
-    rule as :mod:`repro.distributed.sharding`). The lanes added are
-    counted per ``family`` (``repro_backend_pad_configs_total``)."""
-    c = _pow2(n, floor)
-    if dist is not None and dist.enabled:
-        ax = int(dist.mesh.shape[dist.batch_axes[0]])
-        c = ((c + ax - 1) // ax) * ax
+    """Pad the config axis to the smaller of two sizes, each a multiple
+    of the mesh axis size (shard_map needs exact divisibility — same rule
+    as :mod:`repro.distributed.sharding`):
+
+    * a power of two (>= ``floor``), so search rounds with drifting
+      candidate counts reuse compilations;
+    * whole lane tiles on every shard (a multiple of ``_LANES`` times the
+      mesh axis size), which a large grid pads to far fewer lanes.
+
+    Up to ``_LANES`` configs per shard the power of two is never larger.
+    The lanes added are counted per ``family``
+    (``repro_backend_pad_configs_total``)."""
+    ax = (int(dist.mesh.shape[dist.batch_axes[0]])
+          if dist is not None and dist.enabled else 1)
+    c = min(_round_up(_pow2(n, floor), ax),
+            _round_up(max(n, 1), _LANES * ax))
     obs.counter(PAD_CONFIGS[0], float(c - n), help=PAD_CONFIGS[1],
                 family=family)
     return c
@@ -1040,8 +1057,9 @@ def replay_ir_outcomes(
     # ``np.sum`` over the outer axis of a C-order array reduces one
     # stream-row at a time — the same left fold, so times stay bitwise
     # identical to the explicit per-stream loop this replaces. Penalties
-    # use the same axis-0 fold (all terms non-negative, so the naive sum
-    # sits well inside the <= 1e-9 oracle tolerance fsum used to meet).
+    # are summed exactly (``math.fsum``, as the oracle does): a fold in
+    # another order moves a total by an ulp, which can flip a Pareto flag
+    # between two configs whose penalties tie.
     with obs.span("backend.assembly", configs=n_cfg, streams=s):
         fleet_t = cf_time.sum(axis=0)
         fleet_e = cf_energy.sum(axis=0)
@@ -1057,7 +1075,6 @@ def replay_ir_outcomes(
 
         base_tot = float(_total(fleet_be[:, None])[0]) if s else 0.0
         cf_tot = _total(fleet_e)
-        penalty_s = pen.sum(axis=0)
         wake_tot = wakes.sum(axis=0)
         down_tot = downs.sum(axis=0)
         thr_tot = thr.sum(axis=0)
@@ -1072,6 +1089,7 @@ def replay_ir_outcomes(
         # per (config, stream) cell — same float64 values either way
         saved_rows = np.sort(saved_jobs, axis=0).T.tolist()       # [C][S]
         pen_rows = np.sort(pen, axis=0).T.tolist()                # [C][S]
+        penalty_s = [math.fsum(row) for row in pen_rows]
 
         active_t = float(fleet_bt[2]) if s else 0.0
         base_exec_den = float(fleet_be[1] + fleet_be[2]) if s else 0.0

@@ -47,8 +47,13 @@ CORPUS_BUCKETS = {
 #: the widest
 COMPILED_BUCKETS = [(8, 8, 4096, 4096), (256, 512, 16384, 8192),
                     (512, 1024, 16384, 16384)]
-#: padded config axes per grid: (unique downscale (X, Y) pairs, power caps)
-GRID_CONFIG_PADS = {"dense200": (32, 128), "grid10k": (1024, 8192)}
+#: padded config axes per grid on one chip: (unique downscale (X, Y)
+#: pairs, power caps); grid10k's 544 pairs and 7,901 caps fill whole
+#: 128-lane tiles
+GRID_CONFIG_PADS = {"dense200": (32, 128), "grid10k": (640, 7936)}
+#: grid10k's on four chips: whole tiles on every chip need 512-lane
+#: granules, so the power of two is as small
+MESH_CONFIG_PADS = (1024, 8192)
 
 
 @pytest.fixture(scope="module")
@@ -158,7 +163,7 @@ def test_sharded_programs_compile_on_four_chip_mesh(four_chips, pallas_branch,
     """The 10^4-config grid's programs with the config axis sharded over
     the 2x2 host (``dist=config_mesh(4)``), at the widest bucket."""
     key = COMPILED_BUCKETS[-1]
-    pairs, caps = GRID_CONFIG_PADS["grid10k"]
+    pairs, caps = MESH_CONFIG_PADS
     s_b = CORPUS_BUCKETS[key]
     mesh = four_chips.mesh
     with jax.enable_x64():

@@ -6,8 +6,10 @@ plain reference of ``bench/reference.py`` (times, counts, Pareto flags and
 the knee exact, energies and penalties within 1e-9); a repeat bit for bit;
 the packed tables placed once per mesh, whole on every device, so a
 second sweep places only its config-axis arrays, the same bytes on two
-fleet sizes; a one-device sweep beside it on the same IR; and the config
-lanes ``_config_pad`` adds, counted per family.
+fleet sizes; a one-device sweep beside it on the same IR; the config
+lanes ``_config_pad`` adds, counted per family; and a downscale family
+padded to whole lane tiles rather than a power of two, exact on one
+device and on the mesh.
 """
 import pathlib
 import sys
@@ -48,6 +50,13 @@ SMALL = ([common.noop()]
 #: the same without caps: every config-axis array is then independent of
 #: the fleet's size
 NO_CAPS = [s for s in SMALL if s["policy"] != "powercap"]
+#: 17 triggers x 18 cooldowns, each pair at both clock modes: 306 distinct
+#: downscale pairs, which pad to 384 lanes on one device (512 as a power
+#: of two)
+LANE_TILES = ([common.noop()]
+              + [common.downscale(float(x), float(y), mode)
+                 for x in range(1, 18) for y in range(2, 20)
+                 for mode in ("sm_only", "sm_and_mem")])
 
 
 class Fleet:
@@ -66,9 +75,9 @@ class Fleet:
         return front, find_knee(front.outcomes).params
 
 
-def _make(tmp_path_factory, n_devices, seed):
+def _make(tmp_path_factory, n_devices, seed, horizon_s=1500):
     root = tmp_path_factory.mktemp(f"fleet{n_devices}_")
-    generate_cluster(n_devices=n_devices, horizon_s=1500, seed=seed,
+    generate_cluster(n_devices=n_devices, horizon_s=horizon_s, seed=seed,
                      store=TelemetryStore(root, shard_format="npy_dir"),
                      shard_s=500)
     return root
@@ -77,6 +86,14 @@ def _make(tmp_path_factory, n_devices, seed):
 @pytest.fixture(scope="module")
 def small(tmp_path_factory):
     return Fleet(_make(tmp_path_factory, 8, 7), grid200.specs())
+
+
+@pytest.fixture(scope="module")
+def tiled(tmp_path_factory):
+    """A fleet on which the 306 pairs' events, throttled times and savings
+    differ (on ``small`` every downscale config has the same events and
+    no throttled time)."""
+    return Fleet(_make(tmp_path_factory, 6, 7, horizon_s=3600), LANE_TILES)
 
 
 @pytest.fixture(scope="module")
@@ -111,7 +128,7 @@ def _reference(fleet):
     return ref, flags, R.knee(ref)
 
 
-def _assert_matches(front, knee, oracle, ref):
+def _assert_matches(front, knee, oracle, ref=None):
     oracle_knee = find_knee(oracle.outcomes).params
     assert knee == oracle_knee
     for a, b in zip(front.outcomes, oracle.outcomes, strict=True):
@@ -122,6 +139,8 @@ def _assert_matches(front, knee, oracle, ref):
                   "energy_saved_j", "penalty_s", "saved_fraction"):
             assert np.isclose(getattr(a, f), getattr(b, f), rtol=1e-9,
                               atol=1e-9), (a.params, f)
+    if ref is None:
+        return
     cmp = Compare()
     cmp.frontier(front.outcomes, knee, *ref, "mesh sweep")
     assert cmp.mismatches == 0, cmp.notes
@@ -209,6 +228,36 @@ def test_pad_configs_count_the_lanes_added(small, mesh, counted, devices,
     small.sweep(dist, SMALL)
     assert _padded(family="powercap") - before["powercap"] == caps
     assert _padded(family="downscale") - before["downscale"] == pairs
+
+
+@pytest.mark.parametrize("n, devices, lanes", [
+    (3, None, 8), (64, None, 64), (114, None, 128), (129, None, 256),
+    (300, None, 384), (544, None, 640), (7901, None, 7936),
+    (544, 4, 1024), (7901, 4, 8192), (3, 3, 9),
+])
+def test_config_pad_takes_pow2_or_whole_lane_tiles(mesh, counted, n, devices,
+                                                   lanes):
+    """The smaller of a power of two (at least 8) and whole 128-lane tiles
+    on every shard, each a multiple of the mesh's devices."""
+    dist = None if devices is None else B.config_mesh(devices)
+    before = _padded(family="downscale")
+    assert B._config_pad(n, dist, "downscale") == lanes
+    assert _padded(family="downscale") - before == lanes - n
+
+
+@pytest.mark.parametrize("devices, lanes", [(None, 384), (4, 512)])
+def test_sweep_exact_at_lane_tile_pad(tiled, mesh, counted, devices, lanes):
+    """306 pairs run as 384 lanes on one device and 512 on the mesh; both
+    give the NumPy oracle's times, counts, flags and knee exactly. Three
+    of the flags rest on penalties that tie: they hold only because the
+    fleet's penalties are summed exactly, as the oracle sums them."""
+    dist = None if devices is None else mesh
+    oracle = run_sweep(tiled.store, tiled.policies, ir=tiled.ir,
+                       backend="numpy", min_job_duration_s=0.0)
+    before = _padded(family="downscale")
+    front, knee = tiled.sweep(dist)
+    assert _padded(family="downscale") - before == lanes - 306
+    _assert_matches(front, knee, oracle)
 
 
 def test_counters_are_declared_before_first_use(small, counted):
