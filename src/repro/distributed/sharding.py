@@ -14,11 +14,6 @@ Strategy (baseline; hillclimbed variants live in launch/dryrun options):
   kv_heads < |model| (distributed-softmax decode), else kv-heads over
   ``model``. Uneven dims are allowed (GSPMD pads); shard_map inputs are the
   only place that requires exact divisibility.
-
-The what-if replay backend reuses the same mesh/axis conventions for a
-much simpler layout — an embarrassingly-parallel 1-D shard of the policy
-config axis over the batch axis (:func:`repro.whatif.backend.config_mesh`,
-padded to exact divisibility like shard_map inputs here).
 """
 from __future__ import annotations
 

@@ -14,8 +14,8 @@ deadline-aware sweeps of arXiv 2004.08177-style studies — are routine:
   run-weighted integrator (:meth:`BatchedStreamingIntegrator.update_runs`
   / :func:`integrate_runs`) are ported to ``jax.jit``-compiled functions
   vectorized over ``(n_configs, n_runs)``; the config axis is sharded via
-  ``shard_map`` over a :class:`repro.distributed.context.DistContext`
-  mesh (:func:`config_mesh`), so multi-device scales near-linearly —
+  ``shard_map`` over a 1-D :class:`jax.sharding.Mesh`
+  (:func:`config_mesh`), so multi-device scales near-linearly —
   every per-config op is elementwise along the axis, so sharding needs no
   cross-device communication at all. Every chip of the mesh holds the
   packed tables whole, placed once per mesh; the config-axis arrays go
@@ -65,7 +65,6 @@ from repro.core.energy import EnergyBreakdown
 from repro.core.power_model import ClockLevel, PlatformSpec
 from repro.core.states import ClassifierConfig, DEFAULT_CLASSIFIER, DeviceState
 from repro.distributed.compat import shard_map
-from repro.distributed.context import DistContext
 from repro.kernels.run_replay import (cap_bucket_counts, key_words_to_f64,
                                       order_key_words)
 from repro.whatif.policies import (CompositeBatch, DownscaleBatch, NoOpBatch,
@@ -147,40 +146,32 @@ PAD_CONFIGS = ("repro_backend_pad_configs_total",
 # --------------------------------------------------------------------------- #
 # Mesh helper
 # --------------------------------------------------------------------------- #
-def config_mesh(n_devices: int | None = None,
-                axis: str = "data") -> DistContext:
+def config_mesh(n_devices: int | None = None, axis: str = "data") -> Mesh:
     """A 1-D config-axis mesh over the first ``n_devices`` local devices.
 
     Simulate multi-device on CPU with
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the test
-    suite runs with 4). ``DistContext(mesh=None)`` — the default
-    everywhere — keeps the backend single-device.
+    suite runs with 4). ``dist=None`` — the default everywhere — keeps
+    the backend single-device.
     """
     devs = jax.devices()
     n = len(devs) if n_devices is None else min(int(n_devices), len(devs))
-    return DistContext(mesh=Mesh(np.array(devs[:n]), (axis,)),
-                       batch_axes=(axis,))
+    return Mesh(np.array(devs[:n]), (axis,))
 
 
-def _mesh_of(dist: DistContext | None) -> Mesh | None:
-    """The mesh the config axis runs over, or None on one device."""
-    return dist.mesh if dist is not None and dist.enabled else None
-
-
-def _place(hosts: Sequence[np.ndarray], dist: DistContext | None = None,
+def _place(hosts: Sequence[np.ndarray], mesh: Mesh | None = None,
            config_axis: int | None = None) -> tuple[list[jax.Array], int]:
     """Every host-to-device transfer of the backend: ``jnp.asarray`` on the
     default device without a mesh; with one, each array split over the
     mesh along ``config_axis``, or replicated on every chip when it is
     None. Returns the device arrays and the bytes put on chips, counted
     once per chip copy (``repro_backend_placed_bytes_total``)."""
-    mesh = _mesh_of(dist)
     if mesh is None:
         out = [jnp.asarray(a) for a in hosts]
         nbytes = sum(a.nbytes for a in out)
     else:
         spec = (P() if config_axis is None
-                else P(*(None,) * config_axis, dist.batch_axes[0]))
+                else P(*(None,) * config_axis, mesh.axis_names[0]))
         sh = NamedSharding(mesh, spec)
         out = [jax.device_put(a, sh) for a in hosts]
         nbytes = sum(int(np.prod(sh.shard_shape(a.shape))) * a.dtype.itemsize
@@ -223,21 +214,20 @@ class PackedBucket:
     _jnp: dict[tuple, jax.Array] = dataclasses.field(default_factory=dict)
 
     def device(self, *names: str,
-               dist: DistContext | None = None) -> list[jax.Array]:
+               dist: Mesh | None = None) -> list[jax.Array]:
         """Device copies of the named arrays, placed on first use and
         cached per mesh (repeat sweeps and search rounds must not re-upload
         the packed tensors; what only the host reads is never sent). Under
         a mesh every chip holds the whole of each array."""
-        mesh = _mesh_of(dist)
-        missing = [name for name in names if (name, mesh) not in self._jnp]
+        missing = [name for name in names if (name, dist) not in self._jnp]
         if missing:
             with obs.span("backend.upload") as sp:
                 placed, nbytes = _place([self.arrays[n] for n in missing],
                                         dist)
-                self._jnp.update(((n, mesh), a)
+                self._jnp.update(((n, dist), a)
                                  for n, a in zip(missing, placed))
                 sp.set(bytes=nbytes)
-        return [self._jnp[(name, mesh)] for name in names]
+        return [self._jnp[(name, dist)] for name in names]
 
 
 @dataclasses.dataclass
@@ -649,7 +639,7 @@ def _powercap_kernel(cap_hi, cap_lo, caps_hi, caps_lo):
             per_row(caps_hi), per_row(caps_lo)).reshape(s_dim, n_b, c_dim)
 
 
-#: compiled-callable cache: (kernel name, mesh, axis) -> jitted fn.
+#: compiled-callable cache: (kernel name, mesh) -> jitted fn.
 #: Recreating jax.jit wrappers per call would retrace every call; this
 #: keys compilation on the mesh identity so local and sharded variants
 #: coexist.
@@ -661,23 +651,22 @@ _KERNELS = {"downscale": _downscale_kernel, "powercap": _powercap_kernel,
             "integrate": _integrate_runs_kernel}
 
 
-def _get_fn(name: str, dist: DistContext | None):
-    dist_on = dist is not None and dist.enabled and name != "integrate"
-    key = (name, dist.mesh if dist_on else None,
-           dist.batch_axes[0] if dist_on else None)
+def _get_fn(name: str, dist: Mesh | None):
+    mesh = None if name == "integrate" else dist
+    key = (name, mesh)
     fn = _FN_CACHE.get(key)
     if fn is not None:
         return fn
     kernel = _KERNELS[name]
-    if dist_on:
-        ax = dist.batch_axes[0]
+    if mesh is not None:
+        ax = mesh.axis_names[0]
         if name == "downscale":
             in_specs = _DS_STREAM_SPECS + (P(ax),) * 2
             out_specs = (P(None, ax),) * 7
         else:
             in_specs = (P(None, None, None),) * 2 + (P(None, ax),) * 2
             out_specs = P(None, None, ax)
-        kernel = shard_map(kernel, mesh=dist.mesh, in_specs=in_specs,
+        kernel = shard_map(kernel, mesh=mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
     fn = _FN_CACHE[key] = jax.jit(kernel)
     return fn
@@ -692,11 +681,11 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
-def _config_pad(n: int, dist: DistContext | None, family: str,
+def _config_pad(n: int, dist: Mesh | None, family: str,
                 floor: int = 8) -> int:
     """Pad the config axis to the smaller of two sizes, each a multiple
-    of the mesh axis size (shard_map needs exact divisibility — same rule
-    as :mod:`repro.distributed.sharding`):
+    of the mesh axis size (``shard_map`` splits an axis only into equal
+    shards, so the axis must divide exactly by the devices it runs over):
 
     * a power of two (>= ``floor``), so search rounds with drifting
       candidate counts reuse compilations;
@@ -706,8 +695,7 @@ def _config_pad(n: int, dist: DistContext | None, family: str,
     Up to ``_LANES`` configs per shard the power of two is never larger.
     The lanes added are counted per ``family``
     (``repro_backend_pad_configs_total``)."""
-    ax = (int(dist.mesh.shape[dist.batch_axes[0]])
-          if dist is not None and dist.enabled else 1)
+    ax = 1 if dist is None else dist.size
     c = min(_round_up(_pow2(n, floor), ax),
             _round_up(max(n, 1), _LANES * ax))
     obs.counter(PAD_CONFIGS[0], float(c - n), help=PAD_CONFIGS[1],
@@ -913,7 +901,7 @@ def replay_ir_outcomes(
     classifier: ClassifierConfig = DEFAULT_CLASSIFIER,
     dt_s: float = 1.0,
     hosts: Iterable[str] | None = None,
-    dist: DistContext | None = None,
+    dist: Mesh | None = None,
     pad_floor: int = 8,
 ) -> tuple[list[PolicyOutcome], int, int]:
     """Replay a policy grid against a :class:`RunIR` on the JAX backend.
@@ -957,9 +945,8 @@ def replay_ir_outcomes(
     dt = dt_s
 
     if obs.enabled():
-        mesh = _mesh_of(dist)
         obs.gauge("repro_backend_devices",
-                  float(1 if mesh is None else mesh.size),
+                  float(1 if dist is None else dist.size),
                   help="devices the config axis runs over (mesh size when "
                        "sharded, 1 otherwise)")
         for name, help_text in (PLACED_BYTES, PAD_CONFIGS):

@@ -1192,3 +1192,25 @@ def get_ir(store: "TelemetryStore", config: IRConfig | None = None,
         _IR_UNSUPPORTED[cache_key] = (store.total_rows, str(e))
         raise
     return _finish(ir, save=True)
+
+
+def acquire_ir(store: "TelemetryStore", configs: Iterable[Policy],
+               classifier: ClassifierConfig | None = None,
+               dt_s: float = 1.0, *, workers: int = 1, mmap: bool = False,
+               strict: bool = True, verify: bool = False,
+               fault=None) -> RunIR | None:
+    """The IR that replays the most of ``configs`` (:func:`ir_config_for`)
+    through :func:`get_ir`, or None when the row path must replay them
+    all: no config can ride an IR, or the store cannot be held as one
+    (irregular sampling), a ``compact -> row`` step down counted in
+    ``repro_fallbacks_total``."""
+    configs = list(configs)
+    config = ir_config_for(configs, classifier or DEFAULT_CLASSIFIER, dt_s)
+    if not any(ir_supported(p, config) for p in configs):
+        return None
+    try:
+        return get_ir(store, config, workers=workers, mmap=mmap,
+                      strict=strict, verify=verify, fault=fault)
+    except IRUnsupportedError:
+        obs.fallback("compact", "row", "ir_unsupported")
+        return None

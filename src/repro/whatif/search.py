@@ -17,7 +17,10 @@ shared per-row work and wins where per-*config* cost dominates — composite
 or custom families (no row sharing), knob spaces finer than the fixed
 grid's 200 points, or when only the knee neighbourhood matters. On a corpus
 where batched per-row work dominates, the dense sweep is the faster dump
-(see ``BENCH_whatif_search.json``: ``dense_sweep_s`` vs ``search_s``).
+(see ``BENCH_whatif_search.json``: ``dense_sweep_s`` vs ``search_s``, a
+host-CPU record whose timing floors say nothing about the chip; the chip's
+speed record is ``python3 bench/run.py``, ``PERF.md`` and
+``PERF_LEDGER.jsonl``).
 
 The refinement mirrors the data-driven deadline-aware frequency-scaling
 approach of Ilager et al. (budgeted knob search instead of exhaustive
@@ -52,6 +55,7 @@ from typing import TYPE_CHECKING, Callable, Iterable, Sequence
 import repro.obs as obs
 from repro.core.controller import ControllerConfig, DownscaleMode
 from repro.core.imbalance import PoolConfig, PoolPolicy
+from repro.whatif.ir import acquire_ir
 from repro.whatif.policies import (CompositePolicy, DownscalePolicy,
                                    NoOpPolicy, ParkingPolicy, Policy,
                                    PowerCapPolicy)
@@ -511,7 +515,8 @@ def search_frontier(
     reuse one across searches. ``backend="jax"`` additionally runs every
     IR-capable round on the jit'd run-level evaluators
     (:mod:`repro.whatif.backend`), config axis optionally sharded over
-    ``dist`` (:func:`repro.whatif.backend.config_mesh`); candidate counts
+    ``dist``, a :class:`jax.sharding.Mesh` from
+    :func:`repro.whatif.backend.config_mesh`; candidate counts
     are padded to powers of two there, so refinement rounds of drifting
     size reuse compilations.
 
@@ -519,7 +524,8 @@ def search_frontier(
     frontier JSON path) warm-starts the search: the previous frontier's
     Pareto members seed round 0 alongside the coarse grids
     (:func:`seed_points`), so a week-over-week re-search reaches its knee
-    in fewer evaluations (tracked in ``BENCH_whatif_search.json``).
+    in fewer evaluations (tracked on the host CPU in
+    ``BENCH_whatif_search.json``).
 
     Determinism: candidates are generated in family/axis order from sorted
     tried-value sets and evaluated through the batched replayer, so the
@@ -672,22 +678,12 @@ def _search_loop(
     # refinement round: rounds then skip get_ir's freshness re-validation
     # entirely, and a store that grows mid-search cannot shear the search
     # across two IR generations. Configs the handle's config cannot cover
-    # fall back per-config to the row path inside the evaluator, exactly
-    # as before.
+    # fall back per-config to the row path inside the evaluator.
     if compact and ir is None:
-        from repro.core.states import DEFAULT_CLASSIFIER
-        from repro.whatif import ir as ir_mod
-        pols0 = [pol for _, (_, _, pol) in round0]
-        cfg = ir_mod.ir_config_for(
-            pols0, replayer_kwargs.get("classifier") or DEFAULT_CLASSIFIER,
-            replayer_kwargs.get("dt_s", 1.0))
-        if any(ir_mod.ir_supported(p, cfg) for p in pols0):
-            try:
-                ir = ir_mod.get_ir(store, cfg, workers=workers, mmap=mmap,
-                                   strict=strict, verify=verify, fault=fault)
-            except ir_mod.IRUnsupportedError:
-                ir = None          # e.g. irregular sampling: use rows
-                obs.fallback("compact", "row", "ir_unsupported")
+        ir = acquire_ir(store, [pol for _, (_, _, pol) in round0],
+                        replayer_kwargs.get("classifier"),
+                        replayer_kwargs.get("dt_s", 1.0), workers=workers,
+                        mmap=mmap, strict=strict, verify=verify, fault=fault)
 
     evaluate_round(round0)
 

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import TYPE_CHECKING, Iterable, Sequence
 
@@ -41,10 +42,11 @@ import repro.obs as obs
 from repro.core.controller import ControllerConfig, DownscaleMode
 from repro.core.imbalance import PoolConfig, PoolPolicy
 from repro.telemetry.pipeline import map_shard_partitions
+from repro.whatif.ir import acquire_ir, ir_supported
 from repro.whatif.policies import (DownscalePolicy, NoOpPolicy, ParkingPolicy,
                                    Policy, PowerCapPolicy)
 from repro.whatif.replay import (BatchedPolicyReplayer, PolicyReplayer,
-                                 ReplayResult, replay_chunk)
+                                 ReplayResult, replay_chunk, replay_ir)
 
 if TYPE_CHECKING:
     from repro.telemetry.storage import TelemetryStore
@@ -63,7 +65,10 @@ def default_policy_grid(dense: bool = True) -> list[Policy]:
     config-axis batched replay makes the sweep O(rows + configs).
 
     ``dense=False``: the legacy 48-config grid (1 + 24 + 6 + 17) that the
-    committed ``BENCH_whatif_sweep.json`` baseline measures.
+    committed ``BENCH_whatif_sweep.json`` baseline measures: a host-CPU
+    record whose timing floors say nothing about the chip (the chip's
+    speed record is ``python3 bench/run.py``, ``PERF.md`` and
+    ``PERF_LEDGER.jsonl``).
     """
     grid: list[Policy] = [NoOpPolicy()]
     xs = ((0.5, 1.0, 2.0, 3.0, 5.0, 8.0, 10.0, 15.0) if dense
@@ -243,57 +248,61 @@ def _outcome(result: ReplayResult) -> PolicyOutcome:
     )
 
 
-def _assemble(results: list[ReplayResult], n_rows: int,
-              n_runs: int = 0) -> Frontier:
-    return assemble_frontier([_outcome(r) for r in results], n_rows, n_runs)
-
-
 # --------------------------------------------------------------------------- #
 # Evaluation kernel and its fixed-grid caller
 # --------------------------------------------------------------------------- #
+def _row_replayer(policies: Sequence[Policy], batched: bool,
+                  replayer_kwargs: dict):
+    """Row-path replay state for ``policies`` and the call that feeds it a
+    frame: one :class:`BatchedPolicyReplayer` for the whole set
+    (``batched``), or one :class:`PolicyReplayer` per config, sharing only
+    grouping and classification (the reference oracle)."""
+    if batched:
+        replayer = BatchedPolicyReplayer(policies, **replayer_kwargs)
+        return replayer, replayer.update
+    replayers = [PolicyReplayer(p, **replayer_kwargs) for p in policies]
+    return replayers, functools.partial(replay_chunk, replayers)
+
+
+def _merge_rows(a, b):
+    """Disjoint-stream merge of two partitions' row-path replay state."""
+    if isinstance(a, list):
+        for dst, src in zip(a, b):
+            dst.merge(src)
+        return a
+    return a.merge(b)
+
+
+def _finish_rows(replayer) -> tuple[list[ReplayResult], int]:
+    """Results in input order and the replayed job-attributed row count."""
+    if isinstance(replayer, list):
+        n_rows = replayer[0].n_rows if replayer else 0
+        return [r.finalize() for r in replayer], n_rows
+    n_rows = replayer.n_rows              # finalize() resets the counter
+    return replayer.finalize(), n_rows
+
+
 def _replay_partition(
     root: str,
     shard_files: list[str],
     policies: Sequence[Policy],
     mmap: bool,
     replayer_kwargs: dict,
-    strict: bool = True,
-    verify: bool = False,
-) -> tuple[list[PolicyReplayer], list[dict]]:
-    """Stream one shard subset through every policy's replayer (worker body;
-    must stay module-level picklable). The reference oracle path."""
+    strict: bool,
+    verify: bool,
+    batched: bool,
+) -> tuple[object, list[dict]]:
+    """Stream one shard subset through the row path (worker body; must
+    stay module-level picklable)."""
     from repro.telemetry.storage import TelemetryStore
     store = TelemetryStore(root)
-    replayers = [PolicyReplayer(p, **replayer_kwargs) for p in policies]
+    replayer, update = _row_replayer(policies, batched, replayer_kwargs)
     skips: list[dict] = []
     for name in shard_files:
         frame = store.read_shard_or_skip(name, skips, mmap=mmap,
                                          strict=strict, verify=verify)
         if frame is not None:
-            replay_chunk(replayers, frame)
-    return replayers, skips
-
-
-def _replay_partition_batched(
-    root: str,
-    shard_files: list[str],
-    policies: Sequence[Policy],
-    mmap: bool,
-    replayer_kwargs: dict,
-    strict: bool = True,
-    verify: bool = False,
-) -> tuple[BatchedPolicyReplayer, list[dict]]:
-    """Stream one shard subset through the config-axis batched replayer
-    (worker body; must stay module-level picklable)."""
-    from repro.telemetry.storage import TelemetryStore
-    store = TelemetryStore(root)
-    replayer = BatchedPolicyReplayer(policies, **replayer_kwargs)
-    skips: list[dict] = []
-    for name in shard_files:
-        frame = store.read_shard_or_skip(name, skips, mmap=mmap,
-                                         strict=strict, verify=verify)
-        if frame is not None:
-            replayer.update(frame)
+            update(frame)
     return replayer, skips
 
 
@@ -308,7 +317,7 @@ def _ir_skips(ir_obj, hosts: Iterable[str] | None) -> list[dict]:
 
 def _merge_skips(*skip_lists: Sequence[dict]) -> list[dict]:
     """Concatenate skip-record lists, deduplicating by shard file (the IR
-    and a row-fallback recursion may both report the same bad shard)."""
+    and the row path may both report the same bad shard)."""
     seen: set = set()
     out: list[dict] = []
     for lst in skip_lists:
@@ -334,123 +343,6 @@ def _coverage_of(store: "TelemetryStore", hosts: Iterable[str] | None,
                / expected)
 
 
-def _evaluate(
-    configs: Sequence[Policy],
-    store: "TelemetryStore",
-    workers: int = 1,
-    hosts: Iterable[str] | None = None,
-    mmap: bool = False,
-    batched: bool = True,
-    replayer_kwargs: dict | None = None,
-    compact: bool | None = None,
-    ir=None,
-    strict: bool = True,
-    verify: bool = False,
-    fault=None,
-) -> tuple[list[ReplayResult], int, int, list[dict]]:
-    """Kernel body shared by :func:`evaluate` / :func:`run_sweep`: one
-    :class:`ReplayResult` per config in input order, plus the replayed
-    job-attributed row count, (when the compact path ran) the IR's run
-    count, and the shard skip records of a ``strict=False`` replay.
-
-    ``compact=None`` resolves to ``batched`` — the row-exact reference
-    paths (``batched=False`` / ``compact=False``) stay byte-for-byte what
-    they were. With the compact path on, configs the IR supports replay
-    against the run axis (:func:`repro.whatif.replay.replay_ir`); the rest
-    — custom policies, mismatched thresholds, unsupported composites —
-    stream the store through the row path, and an irregularly-sampled
-    store falls back entirely (a ``compact -> row`` fallback in the
-    degradation ladder).
-    """
-    configs = list(configs)
-    replayer_kwargs = replayer_kwargs or {}
-    if compact is None:
-        compact = batched
-
-    if compact:
-        from repro.whatif import ir as ir_mod
-        from repro.whatif.replay import replay_ir
-
-        classifier = replayer_kwargs.get("classifier", None)
-        dt_s = replayer_kwargs.get("dt_s", 1.0)
-        if ir is not None:
-            ir_obj = ir
-        else:
-            from repro.core.states import DEFAULT_CLASSIFIER
-            cfg = ir_mod.ir_config_for(
-                configs, classifier or DEFAULT_CLASSIFIER, dt_s)
-            ir_obj = None
-            if any(ir_mod.ir_supported(p, cfg) for p in configs):
-                try:
-                    ir_obj = ir_mod.get_ir(store, cfg, workers=workers,
-                                           mmap=mmap, strict=strict,
-                                           verify=verify, fault=fault)
-                except ir_mod.IRUnsupportedError:
-                    ir_obj = None       # e.g. irregular sampling: use rows
-                    obs.fallback("compact", "row", "ir_unsupported")
-        if ir_obj is not None:
-            sup = [i for i, p in enumerate(configs)
-                   if ir_mod.ir_supported(p, ir_obj.config)]
-            if sup:
-                ir_kwargs = {k: v for k, v in replayer_kwargs.items()
-                             if k in ("platform_of", "min_job_duration_s",
-                                      "min_interval_s", "classifier", "dt_s")}
-                obs.counter("repro_replay_configs_total", float(len(sup)),
-                            path="compact",
-                            help="policy configs replayed, by execution path")
-                sup_results = replay_ir(
-                    ir_obj, [configs[i] for i in sup], hosts=hosts,
-                    workers=workers, fault=fault, **ir_kwargs)
-                skips = _ir_skips(ir_obj, hosts)
-                results: list[ReplayResult | None] = [None] * len(configs)
-                for i, res in zip(sup, sup_results):
-                    results[i] = res
-                rest = [i for i in range(len(configs)) if results[i] is None]
-                if rest:
-                    obs.counter("repro_replay_row_fallback_configs_total",
-                                float(len(rest)),
-                                help="configs the IR could not cover "
-                                     "(row-path fallback)")
-                    rest_results, _, _, rest_skips = _evaluate(
-                        [configs[i] for i in rest], store, workers=workers,
-                        hosts=hosts, mmap=mmap, batched=batched,
-                        replayer_kwargs=replayer_kwargs, compact=False,
-                        strict=strict, verify=verify, fault=fault)
-                    for i, res in zip(rest, rest_results):
-                        results[i] = res
-                    skips = _merge_skips(skips, rest_skips)
-                selected = ir_obj.select(hosts)
-                n_rows = sum(s.n_rows for s in selected)
-                n_runs = sum(s.n_runs for s in selected)
-                return results, n_rows, n_runs, skips
-
-    if batched:
-        obs.counter("repro_replay_configs_total", float(len(configs)),
-                    path="row_batched",
-                    help="policy configs replayed, by execution path")
-        replayer, skips = map_shard_partitions(
-            store, hosts, workers, _replay_partition_batched,
-            (configs, mmap, replayer_kwargs, strict, verify),
-            merge=lambda a, b: a.merge(b), stage="sweep", fault=fault)
-        n_rows = replayer.n_rows          # finalize() resets the counter
-        return replayer.finalize(), n_rows, 0, skips
-
-    def merge_lists(a: list[PolicyReplayer], b: list[PolicyReplayer]):
-        for dst, src in zip(a, b):
-            dst.merge(src)
-        return a
-
-    obs.counter("repro_replay_configs_total", float(len(configs)),
-                path="row_serial",
-                help="policy configs replayed, by execution path")
-    replayers, skips = map_shard_partitions(
-        store, hosts, workers, _replay_partition,
-        (configs, mmap, replayer_kwargs, strict, verify),
-        merge=merge_lists, stage="sweep", fault=fault)
-    n_rows = replayers[0].n_rows if replayers else 0
-    return [r.finalize() for r in replayers], n_rows, 0, skips
-
-
 def resolve_backend(backend: str) -> str:
     """Resolve an ``evaluate``/``run_sweep`` ``backend`` argument.
 
@@ -471,6 +363,32 @@ def resolve_backend(backend: str) -> str:
     return backend
 
 
+def _replay_rows(
+    configs: list[Policy],
+    store: "TelemetryStore",
+    workers: int,
+    hosts: Iterable[str] | None,
+    mmap: bool,
+    batched: bool,
+    replayer_kwargs: dict,
+    strict: bool,
+    verify: bool,
+    fault,
+) -> tuple[list[ReplayResult], int, list[dict]]:
+    """Stream the store through the row path of ``batched``, one partition
+    per host label: the results in input order, the replayed
+    job-attributed row count and the skip records of a ``strict=False``
+    replay."""
+    obs.counter("repro_replay_configs_total", float(len(configs)),
+                path="row_batched" if batched else "row_serial",
+                help="policy configs replayed, by execution path")
+    replayer, skips = map_shard_partitions(
+        store, hosts, workers, _replay_partition,
+        (configs, mmap, replayer_kwargs, strict, verify, batched),
+        merge=_merge_rows, stage="sweep", fault=fault)
+    return (*_finish_rows(replayer), skips)
+
+
 def _evaluate_outcomes(
     configs: Sequence[Policy],
     store: "TelemetryStore",
@@ -487,19 +405,105 @@ def _evaluate_outcomes(
     verify: bool = False,
     fault=None,
 ) -> tuple[list[PolicyOutcome], int, int, list[dict]]:
-    """Observability wrapper around :func:`_evaluate_outcomes_impl`: every
-    evaluate call runs under a ``whatif.evaluate`` span, with its wall time
-    and per-family config counts recorded when :mod:`repro.obs` is
-    enabled. Pure pass-through otherwise — outcomes are bit-identical with
-    obs on or off."""
+    """Kernel body of :func:`evaluate`, :func:`run_sweep` and the search:
+    one :class:`PolicyOutcome` per config in input order, the replayed
+    job-attributed row count, the IR's run count when an IR path ran (0
+    otherwise), and the shard skip records of a ``strict=False`` replay.
+    Runs under a ``whatif.evaluate`` span; with :mod:`repro.obs` on it
+    records the call's wall time and its configs per family. Outcomes are
+    bit-identical with obs on or off.
+
+    The one place that decides which engine replays which config:
+
+    1. The IR is used when ``compact`` says so; ``compact=None`` means the
+       IR on ``backend="jax"`` and follows ``batched`` on NumPy (where
+       ``batched=False`` keeps the row-exact oracle).
+    2. The IR is ``ir`` or comes from :func:`repro.whatif.ir.acquire_ir`;
+       a store it cannot hold (irregular sampling) steps down
+       ``compact -> row``.
+    3. Configs the IR hosts replay on the jax backend
+       (:func:`repro.whatif.backend.replay_ir_outcomes`, config axis
+       optionally sharded over ``dist``, a mesh from
+       :func:`repro.whatif.backend.config_mesh`) or on NumPy
+       (:func:`repro.whatif.replay.replay_ir`).
+    4. The rest (custom policies, foreign thresholds, other composite
+       orders), or every config when no IR is used, stream the store
+       through the row path of ``batched``.
+
+    Only declared conditions step down, and each is counted: the
+    ``compact -> row`` step above, and a device out of memory, counted as
+    ``jax -> numpy``, after which the decision is made again as NumPy with
+    the same arguments and the IR in hand. Any other error from the jax
+    backend (a compile or programming error) raises, so ``backend="jax"``
+    never runs NumPy unseen. Every rung holds the NumPy oracle contract
+    (times and counts bit-identical, energies and penalties <= 1e-9
+    relative; tests/test_whatif_backend.py), so a step down changes
+    latency, never answers.
+    """
     configs = list(configs)
+    replayer_kwargs = replayer_kwargs or {}
     t0 = time.perf_counter()
     with obs.span("whatif.evaluate", configs=len(configs), backend=backend):
-        out = _evaluate_outcomes_impl(
-            configs, store, workers=workers, hosts=hosts, mmap=mmap,
-            batched=batched, replayer_kwargs=replayer_kwargs,
-            compact=compact, ir=ir, backend=backend, dist=dist,
-            strict=strict, verify=verify, fault=fault)
+        backend = resolve_backend(backend)
+        use_ir = compact if compact is not None else (backend == "jax"
+                                                      or batched)
+        if use_ir and ir is None:
+            ir = acquire_ir(store, configs, replayer_kwargs.get("classifier"),
+                            replayer_kwargs.get("dt_s", 1.0),
+                            workers=workers, mmap=mmap, strict=strict,
+                            verify=verify, fault=fault)
+        sup = ([i for i, p in enumerate(configs)
+                if ir_supported(p, ir.config)]
+               if use_ir and ir is not None else [])
+        ir_kwargs = {k: v for k, v in replayer_kwargs.items()
+                     if k in ("platform_of", "min_job_duration_s",
+                              "min_interval_s", "classifier", "dt_s")}
+        sup_out = None
+        if sup and backend == "jax":
+            from repro.whatif import backend as jax_backend
+            try:
+                sup_out, _, _ = jax_backend.replay_ir_outcomes(
+                    ir, [configs[i] for i in sup], hosts=hosts, dist=dist,
+                    **ir_kwargs)
+            except jax_backend.DeviceError as e:
+                if "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                obs.fallback("jax", "numpy", "device_oom")
+                if compact is None and not batched:
+                    sup = []        # NumPy's reading: the serial row oracle
+            else:
+                obs.counter("repro_replay_configs_total", float(len(sup)),
+                            path="jax",
+                            help="policy configs replayed, by execution path")
+        if sup and sup_out is None:
+            obs.counter("repro_replay_configs_total", float(len(sup)),
+                        path="compact",
+                        help="policy configs replayed, by execution path")
+            sup_out = [_outcome(r) for r in replay_ir(
+                ir, [configs[i] for i in sup], hosts=hosts, workers=workers,
+                fault=fault, **ir_kwargs)]
+
+        outcomes: list[PolicyOutcome | None] = [None] * len(configs)
+        for i, out in zip(sup, sup_out or ()):
+            outcomes[i] = out
+        rest = [i for i, out in enumerate(outcomes) if out is None]
+        n_rows, n_runs, skips = 0, 0, []
+        if rest or not sup:
+            if sup:
+                obs.counter("repro_replay_row_fallback_configs_total",
+                            float(len(rest)),
+                            help="configs the IR could not cover "
+                                 "(row-path fallback)")
+            results, n_rows, skips = _replay_rows(
+                [configs[i] for i in rest], store, workers, hosts, mmap,
+                batched, replayer_kwargs, strict, verify, fault)
+            for i, res in zip(rest, results):
+                outcomes[i] = _outcome(res)
+        if sup:
+            selected = ir.select(hosts)
+            n_rows = sum(s.n_rows for s in selected)
+            n_runs = sum(s.n_runs for s in selected)
+            skips = _merge_skips(_ir_skips(ir, hosts), skips)
     if obs.enabled():
         obs.observe("repro_replay_seconds", time.perf_counter() - t0,
                     help="wall time of evaluate calls")
@@ -507,121 +511,7 @@ def _evaluate_outcomes(
             obs.counter("repro_replay_family_configs_total", float(n),
                         family=fam,
                         help="policy configs replayed, by policy family")
-    return out
-
-
-def _evaluate_outcomes_impl(
-    configs: Sequence[Policy],
-    store: "TelemetryStore",
-    workers: int = 1,
-    hosts: Iterable[str] | None = None,
-    mmap: bool = False,
-    batched: bool = True,
-    replayer_kwargs: dict | None = None,
-    compact: bool | None = None,
-    ir=None,
-    backend: str = "numpy",
-    dist=None,
-    strict: bool = True,
-    verify: bool = False,
-    fault=None,
-) -> tuple[list[PolicyOutcome], int, int, list[dict]]:
-    """:func:`_evaluate` lifted to outcomes, with backend dispatch.
-
-    ``backend="jax"`` routes every IR-capable config through
-    :func:`repro.whatif.backend.replay_ir_outcomes` — the jit'd
-    ``(n_configs, n_runs)`` evaluators, config axis optionally sharded
-    over ``dist`` (a :class:`repro.distributed.context.DistContext` from
-    :func:`repro.whatif.backend.config_mesh`) — and the rest through the
-    NumPy row path; stores without a usable IR fall back to NumPy
-    entirely. The NumPy path remains the oracle: time/count metrics are
-    bit-identical across backends, energies/penalties <= 1e-9 relative
-    (tests/test_whatif_backend.py).
-
-    Degradation ladder: only declared conditions step down, and each is
-    counted. Configs the IR cannot host replay on the row path; a store
-    the IR cannot hold (irregular sampling) degrades ``compact -> row``;
-    a device out of memory is counted as a ``jax -> numpy`` fallback and
-    the same configs replay through the NumPy compact kernel. Any other
-    error from the jax backend (a compile or programming error) raises,
-    so ``backend="jax"`` never runs NumPy unseen. The NumPy oracle
-    contract makes every rung result-equivalent, so degradations change
-    latency, never answers.
-    """
-    configs = list(configs)
-    replayer_kwargs = replayer_kwargs or {}
-    backend = resolve_backend(backend)
-    if backend == "jax" and (compact is None or compact):
-        from repro.whatif import ir as ir_mod
-
-        classifier = replayer_kwargs.get("classifier", None)
-        dt_s = replayer_kwargs.get("dt_s", 1.0)
-        if ir is not None:
-            ir_obj = ir
-        else:
-            from repro.core.states import DEFAULT_CLASSIFIER
-            cfg = ir_mod.ir_config_for(
-                configs, classifier or DEFAULT_CLASSIFIER, dt_s)
-            ir_obj = None
-            if any(ir_mod.ir_supported(p, cfg) for p in configs):
-                try:
-                    ir_obj = ir_mod.get_ir(store, cfg, workers=workers,
-                                           mmap=mmap, strict=strict,
-                                           verify=verify, fault=fault)
-                except ir_mod.IRUnsupportedError:
-                    ir_obj = None       # e.g. irregular sampling: use rows
-                    obs.fallback("compact", "row", "ir_unsupported")
-        if ir_obj is not None:
-            sup = [i for i, p in enumerate(configs)
-                   if ir_mod.ir_supported(p, ir_obj.config)]
-            if sup:
-                ir_kwargs = {k: v for k, v in replayer_kwargs.items()
-                             if k in ("platform_of", "min_job_duration_s",
-                                      "min_interval_s", "classifier", "dt_s")}
-                from repro.whatif import backend as jax_backend
-                try:
-                    sup_out, n_rows, n_runs = jax_backend.replay_ir_outcomes(
-                        ir_obj, [configs[i] for i in sup], hosts=hosts,
-                        dist=dist, **ir_kwargs)
-                except jax_backend.DeviceError as e:
-                    if "RESOURCE_EXHAUSTED" not in str(e):
-                        raise
-                    obs.fallback("jax", "numpy", "device_oom")
-                    sup_out = None
-                if sup_out is not None:
-                    obs.counter("repro_replay_configs_total",
-                                float(len(sup)), path="jax",
-                                help="policy configs replayed, by execution "
-                                     "path")
-                    skips = _ir_skips(ir_obj, hosts)
-                    outcomes: list[PolicyOutcome | None] = \
-                        [None] * len(configs)
-                    for i, out in zip(sup, sup_out):
-                        outcomes[i] = out
-                    rest = [i for i in range(len(configs))
-                            if outcomes[i] is None]
-                    if rest:
-                        obs.counter(
-                            "repro_replay_row_fallback_configs_total",
-                            float(len(rest)),
-                            help="configs the IR could not cover "
-                                 "(row-path fallback)")
-                        rest_results, _, _, rest_skips = _evaluate(
-                            [configs[i] for i in rest], store,
-                            workers=workers, hosts=hosts, mmap=mmap,
-                            batched=batched,
-                            replayer_kwargs=replayer_kwargs, compact=False,
-                            strict=strict, verify=verify, fault=fault)
-                        for i, res in zip(rest, rest_results):
-                            outcomes[i] = _outcome(res)
-                        skips = _merge_skips(skips, rest_skips)
-                    return outcomes, n_rows, n_runs, skips
-        # nothing for the accelerator to do (or it ran out of memory)
-    results, n_rows, n_runs, skips = _evaluate(
-        configs, store, workers=workers, hosts=hosts, mmap=mmap,
-        batched=batched, replayer_kwargs=replayer_kwargs, compact=compact,
-        ir=ir, strict=strict, verify=verify, fault=fault)
-    return [_outcome(r) for r in results], n_rows, n_runs, skips
+    return outcomes, n_rows, n_runs, skips
 
 
 def evaluate(
@@ -669,7 +559,8 @@ def evaluate(
             where the configs support it — the "compact once, replay many"
             fast path, O(runs) per config after a one-off O(rows) build
             that is cached in memory and as a store sidecar. ``None``
-            (default) follows ``batched``; time/count metrics match the row
+            (default) follows ``batched`` on NumPy and means the IR on
+            ``backend="jax"``; time/count metrics match the row
             paths bit-for-bit, energies/penalties to <= 1e-9 relative
             (tests/test_whatif_ir.py). Unsupported configs and
             irregularly-sampled stores fall back to the row path.
@@ -684,10 +575,10 @@ def evaluate(
             ``"auto"`` (jax when importable). The jax backend accelerates
             IR-capable configs on compact replays; everything else runs
             the NumPy path regardless.
-        dist: optional :class:`repro.distributed.context.DistContext`
-            sharding the jax backend's config axis over a device mesh
-            (see :func:`repro.whatif.backend.config_mesh`); ignored by
-            the NumPy backend. Results are mesh-shape-independent.
+        dist: optional 1-D :class:`jax.sharding.Mesh` from
+            :func:`repro.whatif.backend.config_mesh`, sharding the jax
+            backend's config axis over its devices; ignored by the NumPy
+            backend. Results are mesh-shape-independent.
         strict: ``False`` skips unreadable shards instead of raising —
             results are bit-identical to replaying the clean shard subset
             (README "Robustness & dirty telemetry").
@@ -752,12 +643,7 @@ def sweep_frame(frame, policies: Sequence[Policy] | None = None,
     """In-memory convenience: sweep a single :class:`TelemetryFrame`
     (e.g. a DES :class:`PoolResult` telemetry) without a store."""
     policies = list(default_policy_grid() if policies is None else policies)
-    if batched:
-        replayer = BatchedPolicyReplayer(policies, **replayer_kwargs)
-        replayer.update(frame)
-        n_rows = replayer.n_rows          # finalize() resets the counter
-        return _assemble(replayer.finalize(), n_rows)
-    replayers = [PolicyReplayer(p, **replayer_kwargs) for p in policies]
-    replay_chunk(replayers, frame)
-    n_rows = replayers[0].n_rows if replayers else 0
-    return _assemble([r.finalize() for r in replayers], n_rows)
+    replayer, update = _row_replayer(policies, batched, replayer_kwargs)
+    update(frame)
+    results, n_rows = _finish_rows(replayer)
+    return assemble_frontier([_outcome(r) for r in results], n_rows)

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
-from repro.distributed.context import DistContext
 from repro.kernels import run_replay as rr
 from repro.whatif import backend as B
 
@@ -83,8 +82,7 @@ def one_chip(topo):
 def four_chips(topo):
     """The config-axis mesh of ``config_mesh(4)`` over the described
     chips (``config_mesh`` itself asks ``jax.devices()``, the CPU here)."""
-    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
-    return DistContext(mesh=mesh, batch_axes=("data",))
+    return Mesh(np.array(topo.devices[:4]), ("data",))
 
 
 @pytest.fixture()
@@ -165,7 +163,7 @@ def test_sharded_programs_compile_on_four_chip_mesh(four_chips, pallas_branch,
     key = COMPILED_BUCKETS[-1]
     pairs, caps = MESH_CONFIG_PADS
     s_b = CORPUS_BUCKETS[key]
-    mesh = four_chips.mesh
+    mesh = four_chips
     with jax.enable_x64():
         args = _stream_args(NamedSharding(mesh, P()), key, s_b)[name]
         if name == "downscale":

@@ -19,6 +19,8 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+from jax.sharding import NamedSharding, PartitionSpec as P
+
 import repro.obs as obs
 from repro.cluster import generate_cluster
 from repro.telemetry import TelemetryStore
@@ -170,8 +172,8 @@ def test_resident_tables_are_replicated_on_the_mesh(small, mesh, counted):
     for bucket in B.pack_ir(small.ir, 5, 0.0).buckets:
         for arr in bucket.device(*TABLES, dist=mesh):
             assert arr.sharding.is_equivalent_to(
-                mesh.sharding(jax.sharding.PartitionSpec()), arr.ndim)
-            assert arr.sharding.device_set == set(mesh.mesh.devices.flat)
+                NamedSharding(mesh, P()), arr.ndim)
+            assert arr.sharding.device_set == set(mesh.devices.flat)
             assert arr.is_fully_replicated
     # every table was already there: nothing more was placed
     assert _placed() == placed
